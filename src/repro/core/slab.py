@@ -153,7 +153,9 @@ class DeviceSlabCache:
         :class:`DevicePlanes` (fused splice-admit: the bit-plane splice and
         the slot write happen in ONE aliased kernel launch — the demand
         miss warms the slab without ever materializing a standalone spliced
-        tensor)."""
+        tensor).  Only the fused path is bit-exact on the TPU for subnormal
+        and NaN-payload weights: a plain write is an XLA
+        dynamic-update-slice, which on a v5e flushes and quiets them."""
         from repro.kernels import ops
         assert set(tensors) == set(self.shapes), (set(tensors),
                                                   set(self.shapes))
@@ -407,12 +409,11 @@ class PeerSlabMesh:
         f = self._fetch_fns.get(src)
         if f is not None:
             return f
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         names = self.names
 
         @functools.partial(
-            shard_map, mesh=self.mesh,
+            jax.shard_map, mesh=self.mesh,
             in_specs=tuple([P("ep")] * len(names)) + (P(),),
             out_specs=tuple([P("ep")] * len(names)))
         def body(*args):

@@ -105,6 +105,30 @@ def _flatten_ffn(ffn):
     return {k: v for k, v in ffn.items() if k in ("w_gate", "w_up", "w_down")}
 
 
+def drop_routed_experts(params):
+    """``params`` without the routed expert tensors, whose device buffers
+    are released: once :func:`build_store` has written them, the store is
+    their only copy — the regime ZipMoE serves, where the experts do not
+    fit the device.  Routers, shared experts and dense FFNs stay.  The
+    caller's ``params`` must not be used for resident serving afterwards."""
+    def strip(lp):
+        ffn = lp.get("ffn")
+        if ffn is None or "router" not in ffn:
+            return lp
+        for name, arr in _flatten_ffn(ffn).items():
+            if hasattr(arr, "delete"):
+                arr.delete()
+        return {**lp, "ffn": {k: v for k, v in ffn.items()
+                              if k not in ("w_gate", "w_up", "w_down")}}
+
+    dec = params["decoder"]
+    stack = dec["stack"]
+    return {**params, "decoder": {
+        **dec, "prefix": [strip(lp) for lp in dec["prefix"]],
+        "stack": None if stack is None
+        else {k: strip(sub) for k, sub in stack.items()}}}
+
+
 # ----------------------------------------------------------------------------
 # offline build
 # ----------------------------------------------------------------------------

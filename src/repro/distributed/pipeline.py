@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.models.transformer import _superblock, stack_layout
 
 
@@ -86,6 +85,6 @@ def pipeline_forward(stack_params, x_micro, cfg, mesh, *, axis="pod",
 
     in_specs = (P(axis), P())
     out_specs = P()
-    out = shard_map(body, mesh=mesh, in_specs=in_specs,
+    out = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                     out_specs=out_specs, check_vma=False)(stack_params, x_micro)
     return out

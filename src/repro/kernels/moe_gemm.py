@@ -12,8 +12,7 @@ stream, §3.3; recovery itself is §3.2 / kernels/recovery.py).
    two ZipMoE bit-planes (exp u8, sm u8); the kernel splices them to bf16 on
    VREGs and immediately feeds the MXU.  This removes the HBM round-trip of
    the recovered weight (write 2B/elem + read 2B/elem), cutting weight-stream
-   traffic 3× for bandwidth-bound decode GEMMs — napkin math and measured
-   cost-analysis deltas in EXPERIMENTS.md §Perf.
+   traffic 3× for bandwidth-bound decode GEMMs.
    ``zip_gemm_grouped`` is the batched form: one launch over every active
    expert of a decode step instead of a per-expert Python loop.
 
@@ -53,6 +52,18 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.recovery import splice_bf16
+
+
+def pick_block(dim: int, cap: int, align: int = 128) -> int:
+    """Block size along one dimension: the largest ``align``-multiple that
+    is at most ``cap`` and divides ``dim``, else the whole dimension (a
+    full-extent block is always legal).  At Qwen1.5-MoE widths this picks
+    128 for 1408 = 11 × 128, where a whole-dimension block would overflow
+    the v5e scoped VMEM."""
+    for b in range(cap - cap % align, 0, -align):
+        if dim % b == 0:
+            return b
+    return dim
 
 
 # ----------------------------------------------------------------------------
@@ -170,18 +181,17 @@ def _splice_admit_kernel(slot_ref, buf_ref, exp_ref, sm_ref, o_ref):
 
 
 def slab_splice_admit(buf: jnp.ndarray, exp: jnp.ndarray, sm: jnp.ndarray,
-                      slot: jnp.ndarray, *, block_d: int = 512,
-                      block_f: int = 512,
+                      slot: jnp.ndarray, *,
                       interpret: bool = False) -> jnp.ndarray:
     """One-launch fused splice + slab write: splice the (exp, sm) u8
     bit-planes [d, f] to bf16 on VREGs and store them into ``buf[slot]`` of
     the donated slab buffer [capacity, d, f] — ``input_output_aliases``
     turns the write in-place, so a demand miss warms the slab as a side
-    effect of its recovery instead of paying splice + copy."""
+    effect of its recovery instead of paying splice + copy.  Block sizes
+    are :func:`pick_block` of each dimension (cap 512)."""
     _, D, F = buf.shape
     assert exp.shape == (D, F) and sm.shape == (D, F), (exp.shape, buf.shape)
-    block_d, block_f = min(block_d, D), min(block_f, F)
-    assert D % block_d == 0 and F % block_f == 0, (buf.shape, block_d, block_f)
+    block_d, block_f = pick_block(D, 512), pick_block(F, 512)
     grid = (D // block_d, F // block_f)
     slots = jnp.asarray(slot, jnp.int32).reshape(1)
     return pl.pallas_call(

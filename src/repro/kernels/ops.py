@@ -1,8 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On non-TPU backends the kernels run in ``interpret=True`` mode (Python
-emulation of the kernel body — used by CI/tests on CPU); on TPU they compile
-to Mosaic.
+On a TPU every dispatcher compiles its kernel with Mosaic.  Elsewhere (the
+CPU test hosts) a dispatcher runs its jitted XLA oracle or the kernel in
+``interpret=True`` mode; neither is ever taken on a TPU.
 """
 from __future__ import annotations
 
@@ -15,10 +15,9 @@ from repro.kernels import recovery
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    """Whether the default device is a TPU.  A backend that fails to
+    initialise raises here rather than silently selecting the CPU path."""
+    return jax.devices()[0].platform == "tpu"
 
 
 def _pad_to(x: jnp.ndarray, m: int) -> jnp.ndarray:
@@ -31,7 +30,7 @@ def bucket_rows(n: int, align: int = 8) -> int:
     next 128-multiple.  Decode-step token counts drift every step; padding
     each per-expert group (or the padded-path column count) to a fixed rung
     instead of its exact size keeps the GEMM jit cache to a handful of
-    shapes instead of recompiling mid-serve (the `_pick_block(C, ...)`
+    shapes instead of recompiling mid-serve (the `pick_block(C, ...)`
     churn).  `align` floors the rung (MXU sublane alignment)."""
     n = max(int(n), align)
     if n <= 128:
@@ -49,11 +48,23 @@ def recover_bf16(exp: jnp.ndarray, sm: jnp.ndarray, shape=None, *,
                  interpret: bool = None) -> jnp.ndarray:
     """Flat (or any-shape) u8 planes -> bf16 array of `shape`.
 
-    Pads + reshapes to a 2-D tile-aligned layout, runs the Pallas kernel,
-    slices the result back.
+    A 2-D `shape` that tiles (rows a multiple of 32, columns of 128 — every
+    expert tensor of the served models) runs the kernel in its own shape,
+    so the bf16 result leaves the kernel untouched.  Any other buffer is
+    padded to a 2-D tile-aligned layout and sliced back: bit-exact on the
+    CPU, but on a TPU v5e XLA's reshape/slice of bf16 flushed subnormals
+    and quieted NaN payloads (507 of the 65,536 patterns).
     """
+    from repro.kernels.moe_gemm import pick_block
     interpret = (not _on_tpu()) if interpret is None else interpret
     shape = tuple(shape) if shape is not None else exp.shape
+    if (len(shape) == 2 and shape[0] % 32 == 0 and shape[1] % 128 == 0
+            and block_m is None and block_n is None):
+        return recovery.recover_bf16_2d(
+            exp.reshape(shape), sm.reshape(shape),
+            block_m=pick_block(shape[0], recovery.DEFAULT_BLOCK_M, 32),
+            block_n=pick_block(shape[1], recovery.DEFAULT_BLOCK_N),
+            interpret=interpret)
     n = int(exp.size)
     bm = block_m or (8 if interpret else recovery.DEFAULT_BLOCK_M)
     bn = block_n or (128 if interpret else recovery.DEFAULT_BLOCK_N)
@@ -126,14 +137,14 @@ def slab_gemm(x: jnp.ndarray, buf: jnp.ndarray, tile_slot, *,
     [T // block_c].  TPU: the Mosaic megakernel; elsewhere: the jitted XLA
     oracle (same bits, no interpret-mode grid overhead)."""
     ts = jnp.asarray(tile_slot, jnp.int32)
-    if interpret is None and _on_tpu():
-        return _slab_gemm_tpu(x, buf, ts, block_c=block_c, block_d=block_d,
-                              block_f=block_f)
     if interpret:
         from repro.kernels import moe_gemm
         return moe_gemm.slab_ragged_gemm(x, buf, ts, block_c=block_c,
                                          block_d=block_d, block_f=block_f,
                                          interpret=True)
+    if _on_tpu():
+        return _slab_gemm_tpu(x, buf, ts, block_c=block_c, block_d=block_d,
+                              block_f=block_f)
     return _slab_gemm_oracle(x, buf, ts, block_c=block_c)
 
 
@@ -155,7 +166,12 @@ def _splice_set_oracle(buf: jnp.ndarray, slot: jnp.ndarray,
     from repro.core import bitfield
     w = bitfield.reconstruct_jnp(exp.reshape(-1),
                                  sm.reshape(-1)).reshape(buf.shape[1:])
-    return jax.lax.dynamic_update_index_in_dim(buf, w, slot, 0)
+    # the update runs on the integer bits: the CPU backend quiets
+    # signalling-NaN payloads in a bf16 dynamic-update-slice
+    bits = jax.lax.dynamic_update_index_in_dim(
+        jax.lax.bitcast_convert_type(buf, jnp.uint16),
+        jax.lax.bitcast_convert_type(w, jnp.uint16), slot, 0)
+    return jax.lax.bitcast_convert_type(bits, buf.dtype)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,), static_argnames=())

@@ -9,7 +9,8 @@ bf16 tile back.  The op is purely memory-bound; the BlockSpec keeps the
 HBM→VMEM pipeline saturated and the MXU idle.
 
 Grid: 2-D over (M / block_m, N / block_n).  Inputs must be tile-padded —
-``ops.recover_bf16`` handles padding/reshaping for arbitrary flat buffers.
+``ops.recover_bf16`` runs a tile-aligned 2-D tensor in its own shape and
+pads/reshapes any other buffer.
 """
 from __future__ import annotations
 
@@ -30,11 +31,20 @@ def splice_bf16(exp, sm):
     7 mantissa bits in bits 0..6.  Shared by every kernel that recovers
     weights in-flight (this module's recovery kernel, ``moe_gemm.zip_gemm``
     and its grouped variant, and the aliased slab splice-admit) so the bit
-    semantics live in exactly one place."""
-    e = exp.astype(jnp.uint16)
-    s = sm.astype(jnp.uint16)
-    u = ((s & jnp.uint16(0x80)) << 8) | (e << 7) | (s & jnp.uint16(0x7F))
-    return jax.lax.bitcast_convert_type(u, jnp.bfloat16)
+    semantics live in exactly one place.
+
+    The shifts run on 32-bit lanes: Mosaic cannot legalize ``shli`` on
+    packed 16-bit vectors.  The result is narrowed by an integer truncation
+    and reinterpreted by an in-kernel same-width bitcast, never converted
+    as a float, so subnormal exponents and NaN payloads survive bit for
+    bit.  Outside a kernel it is not so on the TPU: on a v5e, XLA's
+    bitcast, reshape, slice, dynamic-update-slice and gather of bf16 each
+    flushed subnormals and quieted NaN payloads, so a recovered tensor
+    must leave the kernel in its final shape and place."""
+    e = exp.astype(jnp.uint32)
+    s = sm.astype(jnp.uint32)
+    u = ((s & jnp.uint32(0x80)) << 8) | (e << 7) | (s & jnp.uint32(0x7F))
+    return jax.lax.bitcast_convert_type(u.astype(jnp.uint16), jnp.bfloat16)
 
 
 def _recover_kernel(exp_ref, sm_ref, out_ref):

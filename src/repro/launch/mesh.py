@@ -10,17 +10,22 @@ crosses pods over DCN); ``model`` carries TP/EP within a pod's ICI domain.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh (tests use small ones, e.g. (2, 2))."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """Arbitrary mesh (tests use small ones, e.g. (2, 2)).  Axes are
+    ``Auto``: the sharding rules and ``shard_map`` bodies of this repo
+    leave propagation to the compiler, which ``jax.make_mesh``'s
+    ``Explicit`` default (JAX >= 0.7) rejects."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 # TPU v5e hardware constants (roofline denominators)

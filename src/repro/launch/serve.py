@@ -4,6 +4,15 @@ params.
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2-moe-a2.7b \\
       --mode zipmoe --requests 8 --max-new 16
 
+Model size: without ``--layers`` the arch runs as a small smoke preset
+(d_model 256, 6 layers, vocab 2048) that any CPU serves in seconds.
+``--layers N`` serves the registered config at its published widths with
+the depth cut to N layers (random weights from seed 0), e.g.
+qwen2-moe-a2.7b at ``--layers 4`` on one TPU v5e.
+
+Compilation cache: ``JAX_COMPILATION_CACHE_DIR`` when set, else
+``.jax_cache/`` in the checkout (:func:`enable_compile_cache`).
+
 --mode resident     : standard in-memory serving (BatchServer)
 --mode zipmoe       : routed experts live ONLY in the compressed store; every
                       MoE layer fetches through cache pools + the Alg-1
@@ -81,6 +90,9 @@ transition counts) next to the ``overlap:`` line.
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import os
+import sys
 import tempfile
 import time
 
@@ -88,12 +100,67 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_smoke_config
+from repro.configs import get_config, get_smoke_config
 from repro.core.faults import FaultPlan
-from repro.core.store import build_store
-from repro.models import init_cache, init_params
+from repro.core.store import build_store, drop_routed_experts
+from repro.models import init_params
 from repro.serving.server import BatchServer
 from repro.serving.zipserve import ZipServer
+
+# the checkout: src/repro/launch/serve.py -> three levels up from src/
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory: ``JAX_COMPILATION_CACHE_DIR`` when set (JAX reads
+    it itself, so nothing else is set), else ``.jax_cache/`` in the
+    checkout — a fixed path, since the path is part of the cache key."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def model_config(arch: str, layers=None):
+    """The served config: the smoke preset without ``layers``, else the
+    registered config at its published widths cut to ``layers`` layers."""
+    if layers is None:
+        return get_smoke_config(arch, d_model=256, n_layers=6,
+                                vocab_size=2048)
+    cfg = get_config(arch)
+    if not 1 <= layers <= cfg.n_layers:
+        raise ValueError(f"--layers must be in [1, {cfg.n_layers}] for "
+                         f"{arch}, got {layers}")
+    return dataclasses.replace(cfg, n_layers=layers)
+
+
+def init_model(cfg, seed: int):
+    """Seeded random weights, built by one compiled program (no eager
+    per-layer temporaries at published widths)."""
+    return jax.jit(init_params, static_argnums=1)(jax.random.PRNGKey(seed),
+                                                  cfg)
+
+
+def build_zipmoe_server(params, cfg, store_dir: str, *, max_batch: int,
+                        max_len: int, max_concurrency=None,
+                        continuous: bool = True, **zip_kw) -> BatchServer:
+    """The served ZipMoE stack: build the compressed store from ``params``,
+    release the device buffers of the routed experts (the store is now
+    their only copy, so ``params`` no longer serves the resident path), and
+    put a :class:`BatchServer` over a :class:`ZipServer` on it.  ``zip_kw``
+    goes to ZipServer; the returned server's ZipServer is ``.zip``."""
+    t0 = time.perf_counter()
+    store = build_store(params, cfg, store_dir)
+    print(f"store: {store_dir} ratio={store.ratio():.3f} "
+          f"rho={store.rho():.3f} build_s={time.perf_counter() - t0:.1f}")
+    zs = ZipServer(drop_routed_experts(params), cfg, store_dir, **zip_kw)
+    return BatchServer(None, cfg, max_batch=max_batch, max_len=max_len,
+                       zip_server=zs, max_concurrency=max_concurrency,
+                       continuous=continuous)
 
 
 def print_sched_telemetry(zs, args):
@@ -147,6 +214,10 @@ def print_sched_telemetry(zs, args):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-moe-a2.7b")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="serve the arch at its published widths with the "
+                         "depth cut to N layers (default: the small smoke "
+                         "preset)")
     ap.add_argument("--mode", default="zipmoe",
                     choices=["resident", "zipmoe", "zipmoe-batch"])
     ap.add_argument("--no-prefetch", action="store_true",
@@ -261,8 +332,15 @@ def main():
             ap.error("--pool-sizes expects exactly 4 comma-separated "
                      "integers (F,C,S,E), e.g. 2,2,4,8")
 
-    cfg = get_smoke_config(args.arch, d_model=256, n_layers=6, vocab_size=2048)
-    params = init_params(jax.random.PRNGKey(0), cfg)
+    enable_compile_cache()
+    try:
+        cfg = model_config(args.arch, args.layers)
+    except ValueError as e:
+        ap.error(str(e))
+    if args.layers is not None:
+        print(f"config: {cfg.name} at published widths, depth cut to "
+              f"{cfg.n_layers} of {get_config(args.arch).n_layers} layers")
+    params = init_model(cfg, 0)
     rng = np.random.default_rng(0)
 
     if args.mode == "resident":
@@ -276,40 +354,33 @@ def main():
 
     # ---- ZipMoE mode -------------------------------------------------------
     store_dir = args.store_dir or tempfile.mkdtemp(prefix="zipmoe_store_")
-    store = build_store(params, cfg, store_dir)
-    print(f"store: {store_dir} ratio={store.ratio():.3f} rho={store.rho():.3f}")
-    zs = ZipServer(params, cfg, store_dir, L=args.workers,
-                   pool_sizes=pool_sizes,
-                   bandwidth_gbps=args.bandwidth_gbps,
-                   prefetch=not args.no_prefetch,
-                   ffn_impl=args.ffn_impl,
-                   cache_mode=args.cache_mode,
-                   flat_capacity=args.flat_capacity,
-                   flat_policy=args.flat_policy, delta=args.delta,
-                   profile_p_times=args.profile_p_times,
-                   cross_layer_depth=args.cross_layer_depth,
-                   freq_decay=args.freq_decay,
-                   cache_window=args.cache_window,
-                   device_cache=args.device_cache,
-                   mem_budget=args.mem_budget,
-                   replan_every=args.replan_every,
-                   plan_step=args.plan_step,
-                   budget_split=args.budget_split,
-                   mesh_devices=args.mesh,
-                   peer_budget=args.peer_budget,
-                   verify=False if args.no_verify else None,
-                   faults=(FaultPlan.parse(args.fault_plan)
-                           if args.fault_plan else None),
-                   fetch_deadline_s=args.fetch_deadline or None)
+    srv = build_zipmoe_server(
+        params, cfg, store_dir, max_batch=args.batch,
+        max_len=args.prompt_len + args.max_new,
+        max_concurrency=args.max_concurrency,
+        continuous=not args.static_batch,
+        L=args.workers, pool_sizes=pool_sizes,
+        bandwidth_gbps=args.bandwidth_gbps,
+        prefetch=not args.no_prefetch, ffn_impl=args.ffn_impl,
+        cache_mode=args.cache_mode, flat_capacity=args.flat_capacity,
+        flat_policy=args.flat_policy, delta=args.delta,
+        profile_p_times=args.profile_p_times,
+        cross_layer_depth=args.cross_layer_depth,
+        freq_decay=args.freq_decay, cache_window=args.cache_window,
+        device_cache=args.device_cache, mem_budget=args.mem_budget,
+        replan_every=args.replan_every, plan_step=args.plan_step,
+        budget_split=args.budget_split, mesh_devices=args.mesh,
+        peer_budget=args.peer_budget,
+        verify=False if args.no_verify else None,
+        faults=(FaultPlan.parse(args.fault_plan)
+                if args.fault_plan else None),
+        fetch_deadline_s=args.fetch_deadline or None)
+    del params                        # routed experts now live in the store
+    zs = srv.zip
 
     if args.mode == "zipmoe-batch":
         arrivals = ([float(x) for x in args.arrival_trace.split(",")]
                     if args.arrival_trace else [0.0])
-        srv = BatchServer(None, cfg, max_batch=args.batch,
-                          max_len=args.prompt_len + args.max_new,
-                          zip_server=zs,
-                          max_concurrency=args.max_concurrency,
-                          continuous=not args.static_batch)
         for i in range(args.requests):
             srv.submit(rng.integers(0, cfg.vocab_size, args.prompt_len),
                        args.max_new, arrival_s=arrivals[i % len(arrivals)])
@@ -331,6 +402,11 @@ def main():
         print("cache:", srv.cache_summary())
         print_sched_telemetry(zs, args)
         zs.close()
+        n_failed = sum(1 for r in srv.finished if r.error)
+        if n_failed and not args.fault_plan:
+            # a failed request outside fault injection is a fault of the
+            # serving path (a kernel or fetch error), not an outcome
+            sys.exit(f"error: {n_failed} request(s) failed")
         return
 
     B = args.batch

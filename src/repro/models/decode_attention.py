@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.models.layers import apply_mrope, apply_rope, rms_norm_headwise
 
 NEG_INF = -1e30
@@ -95,7 +94,7 @@ def gqa_decode_seqsharded(p, x, cfg, cache, pos, mesh, *, axis="model",
     ba = batch_axes
     spec_kv = P(ba, axis, None, None)
     spec_new = P(ba, None, None, None)
-    out, ck, cv = shard_map(
+    out, ck, cv = jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_new, spec_new, spec_new, spec_kv, spec_kv, P()),
         out_specs=(spec_new, spec_kv, spec_kv),
@@ -156,7 +155,7 @@ def mla_decode_seqsharded(p, x, cfg, cache, pos, mesh, *, axis="model",
     spec = P(ba, axis, None)
     spec_q = P(ba, None, None, None)
     spec_new = P(ba, None, None)
-    o_c, ckv, kr = shard_map(
+    o_c, ckv, kr = jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_q, spec_q, spec_new, spec_new, spec, spec, P()),
         out_specs=(spec_q, spec, spec),

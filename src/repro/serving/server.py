@@ -317,6 +317,8 @@ class BatchServer:
         for b, r in enumerate(batch):
             r.ttft = now - r.submitted
             r.output.append(int(tok[b]))
+            if r.record_logits:
+                r.logits.append(np.asarray(logits[b], np.float32))
             if len(r.output) >= r.max_new_tokens:
                 r.done = now
             else:
@@ -331,6 +333,8 @@ class BatchServer:
             for b in list(alive):
                 r = batch[b]
                 r.output.append(int(tok[b]))
+                if r.record_logits:
+                    r.logits.append(np.asarray(lg[b, -1], np.float32))
                 if len(r.output) >= r.max_new_tokens:
                     r.done = now
                     alive.discard(b)

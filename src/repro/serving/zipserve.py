@@ -93,6 +93,7 @@ from repro.core.faults import FetchError, FetchTimeout, StepFault
 from repro.core.profiles import GemmProfiler
 from repro.core.slab import SlotRef
 from repro.core.store import ExpertStore
+from repro.kernels.moe_gemm import pick_block
 from repro.kernels.ops import (bucket_rows, fused_zip_gemm,
                                grouped_expert_gemm, slab_gemm, zip_gemm_batch)
 from repro.models import attention as attn_lib
@@ -116,11 +117,6 @@ def _planes_recover(exp: np.ndarray, sm, shape) -> BitPlanes:
     sm_arr = (np.frombuffer(sm, np.uint8)
               if isinstance(sm, (bytes, bytearray)) else np.asarray(sm))
     return BitPlanes(np.asarray(exp), sm_arr, tuple(shape))
-
-
-def _pick_block(dim: int, cap: int) -> int:
-    """Largest legal Pallas block: `cap` when it divides, else the whole dim."""
-    return cap if dim % cap == 0 else dim
 
 
 class ZipServer:
@@ -339,9 +335,9 @@ class ZipServer:
             wg = jnp.asarray(rng.standard_normal((ne, d, f)),
                              jnp.bfloat16) if "w_gate" in shapes else None
             gg = lambda a, w: grouped_expert_gemm(
-                a, w, block_c=_pick_block(cols, 128),
-                block_d=_pick_block(a.shape[-1], 512),
-                block_f=_pick_block(w.shape[-1], 128))
+                a, w, block_c=pick_block(cols, 128),
+                block_d=pick_block(a.shape[-1], 512),
+                block_f=pick_block(w.shape[-1], 128))
 
             def once():
                 h = jax.nn.silu(gg(x, wg)) * gg(x, wu) if wg is not None \
@@ -835,8 +831,8 @@ class ZipServer:
         C = xg.shape[1]
         self._note_gemm_shape("grouped", len(ids), C)
         gg = lambda a, w: grouped_expert_gemm(
-            a, w, block_c=_pick_block(C, 128), block_d=_pick_block(a.shape[-1], 512),
-            block_f=_pick_block(w.shape[-1], 128))
+            a, w, block_c=pick_block(C, 128), block_d=pick_block(a.shape[-1], 512),
+            block_f=pick_block(w.shape[-1], 128))
         if "w_gate" in weights[ids[0]]:
             h = jax.nn.silu(gg(xg, stack("w_gate"))) * gg(xg, stack("w_up"))
         else:
@@ -871,8 +867,8 @@ class ZipServer:
         def sg(a, src):                                    # one megakernel
             buf, slots = src
             return slab_gemm(a, buf, slots[tile_row], block_c=block_c,
-                             block_d=_pick_block(a.shape[-1], 512),
-                             block_f=_pick_block(buf.shape[-1], 128))
+                             block_d=pick_block(a.shape[-1], 512),
+                             block_f=pick_block(buf.shape[-1], 128))
 
         if "w_gate" in weights[ids[0]]:
             h = jax.nn.silu(sg(xg, self._slab_sources("w_gate", weights,
@@ -912,9 +908,9 @@ class ZipServer:
         def zg(a, pl):
             exp, sm = pl
             return zip_gemm_batch(a, exp, sm,
-                                  block_c=_pick_block(C, 128),
-                                  block_d=_pick_block(exp.shape[1], 512),
-                                  block_f=_pick_block(exp.shape[2], 128))
+                                  block_c=pick_block(C, 128),
+                                  block_d=pick_block(exp.shape[1], 512),
+                                  block_f=pick_block(exp.shape[2], 128))
 
         if "w_gate" in weights[ids[0]]:
             h = jax.nn.silu(zg(xg, planes("w_gate"))) * zg(xg, planes("w_up"))
@@ -940,8 +936,8 @@ class ZipServer:
             return fused_zip_gemm(
                 a, jnp.asarray(planes.exp).reshape(D, F),
                 jnp.asarray(planes.sm).reshape(D, F),
-                block_c=_pick_block(a.shape[0], 128),
-                block_d=_pick_block(D, 512), block_f=_pick_block(F, 128))
+                block_c=pick_block(a.shape[0], 128),
+                block_d=pick_block(D, 512), block_f=pick_block(F, 128))
 
         comb = jnp.zeros((B + 1, d), jnp.float32)
         for r, e in enumerate(ids):   # loop-ok: validation fallback path

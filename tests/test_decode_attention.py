@@ -18,12 +18,13 @@ SCRIPT = textwrap.dedent("""
     from repro.configs import get_smoke_config
     from repro.models import init_params, decode_step, init_cache
     from repro.distributed.sharding import cache_pspecs
+    from repro.launch.mesh import make_mesh
 
     failures = []
     for arch in ["granite-8b", "qwen3-14b", "deepseek-v2-236b",
                  "jamba-v0.1-52b"]:
         cfg = dataclasses.replace(get_smoke_config(arch), dtype="float32")
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         params = init_params(jax.random.PRNGKey(0), cfg)
         B, S = 4, 64
         cache = jax.tree.map(lambda x: x + 0.01, init_cache(cfg, B, S))

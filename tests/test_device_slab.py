@@ -79,6 +79,21 @@ def test_slab_donated_write_is_in_place():
     assert old.is_deleted()
 
 
+def test_fused_admit_keeps_every_bf16_pattern():
+    """A demand miss's fused splice-admit stores all 65,536 bf16 bit
+    patterns unchanged in its slot, signalling-NaN payloads and subnormals
+    included (the store's lossless claim)."""
+    from repro.core import bitfield
+    from repro.core.slab import DevicePlanes
+    pats = np.arange(1 << 16, dtype=np.uint16)
+    exp, sm = bitfield.decompose_np(pats.view(bitfield.BF16))
+    slab = DeviceSlabCache(0, {"w": (256, 256)}, capacity=2)
+    slab.put(2, {"w": DevicePlanes(jnp.asarray(exp), jnp.asarray(sm),
+                                   (256, 256))})
+    got = np.asarray(slab.bufs["w"])[slab.slot_of[2]].view(np.uint16)
+    assert np.array_equal(got.reshape(-1), pats)
+
+
 def test_slab_capacity_overflow_asserts():
     slab = DeviceSlabCache(0, {"w": (1, 1)}, capacity=1)
     slab.put(0, {"w": jnp.zeros((1, 1), jnp.bfloat16)})

@@ -81,6 +81,66 @@ def test_batch_server(setup):
     assert m["n_requests"] == 6 and m["throughput_tok_s"] > 0
 
 
+def test_batch_server_records_logits_on_epoch_path(setup):
+    """The resident (epoch) path captures one logits row per emitted token
+    when asked, as the continuous path does; greedy picks each argmax."""
+    cfg, params = setup
+    srv = BatchServer(params, cfg, max_batch=2)
+    rng = np.random.default_rng(3)
+    for _ in range(2):
+        srv.submit(rng.integers(0, cfg.vocab_size, 8), max_new_tokens=3,
+                   record_logits=True)
+    for r in srv.run():
+        assert len(r.logits) == len(r.output) == 3
+        assert [int(np.argmax(row)) for row in r.logits] == r.output
+
+
+def test_model_config_published_widths_with_depth_cut():
+    from repro.launch.serve import model_config
+    cfg = model_config("qwen1.5-moe-a2.7b", 4)
+    assert (cfg.n_layers, cfg.d_model, cfg.d_expert, cfg.vocab_size,
+            cfg.n_experts, cfg.top_k) == (4, 2048, 1408, 151936, 60, 4)
+    assert model_config("qwen1.5-moe-a2.7b").d_model == 256   # smoke preset
+    with pytest.raises(ValueError):
+        model_config("qwen1.5-moe-a2.7b", 25)
+
+
+def test_build_zipmoe_server_serves_from_store_only(tmp_path):
+    """The entry-point stack: the store is built, the routed experts' device
+    buffers are released, and the same requests come back from ZipMoE with
+    logits within bf16 noise of a dropless resident reference."""
+    import dataclasses
+
+    from repro.launch.serve import build_zipmoe_server, init_model
+    cfg = get_smoke_config("qwen2-moe-a2.7b")
+    params = init_model(cfg, 0)
+    ref_cfg = dataclasses.replace(cfg,
+                                  capacity_factor=cfg.n_experts / cfg.top_k)
+    prompts = [np.random.default_rng(i).integers(0, cfg.vocab_size, 8)
+               for i in range(2)]
+
+    def serve(srv):
+        for p in prompts:
+            srv.submit(p, max_new_tokens=2, record_logits=True)
+        return sorted(srv.run(), key=lambda r: r.rid)
+
+    ref = serve(BatchServer(params, ref_cfg, max_batch=2, max_len=16))
+    experts = params["decoder"]["stack"]["sub_0"]["ffn"]["w_up"]
+    srv = build_zipmoe_server(params, cfg, str(tmp_path), max_batch=2,
+                              max_len=16, device_cache=True,
+                              pool_sizes={"F": 2, "C": 1, "S": 1, "E": 2})
+    try:
+        assert experts.is_deleted()
+        assert all("w_up" not in lp["ffn"] for lp in srv.zip.layers)
+        got = serve(srv)
+    finally:
+        srv.zip.close()
+    assert not any(r.error for r in got)
+    for r, g in zip(ref, got):
+        a, b = r.logits[0], g.logits[0]          # same prompt, same input
+        assert np.linalg.norm(b - a) / np.linalg.norm(a) < 2 ** -4
+
+
 def test_sampling_temperature():
     logits = jnp.asarray([[0.0, 10.0, 0.0]])
     greedy = sample_tokens(logits, jax.random.PRNGKey(0), 0.0)
