@@ -24,9 +24,8 @@ PAPER_SPECS: Dict[str, MoESpec] = {
                                 d_model=1024, d_expert=2816, n_tensors=2),
 }
 
-# Edge testbeds (§5): Jetson AGX Orin 64G / 32G + Samsung 970 EVO (3.5 GB/s)
+# Edge testbed (§5): Jetson AGX Orin 64G + Samsung 970 EVO (3.5 GB/s)
 HW1 = HW(storage_bw=3.5e9, dec_bw=1.2e9, L=6, flop_rate=30e12)   # Orin 64G
-HW2 = HW(storage_bw=3.5e9, dec_bw=0.9e9, L=4, flop_rate=15e12)   # Orin 32G
 
 
 def expert_store_bytes(spec: MoESpec) -> int:

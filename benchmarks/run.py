@@ -4,12 +4,10 @@
 prints ``name,us_per_call,derived`` CSV covering:
   fig2/fig3  compression entropy + ratios        (benchmarks/compression.py)
   fig4       decompress-vs-I/O overlap           (benchmarks/overlap.py)
-  fig7       TPOT/TTFT vs memory budget          (benchmarks/serving_latency.py)
   fig8       throughput vs batch size            (benchmarks/throughput.py)
   fig9       end-to-end latency vs output len    (benchmarks/e2e.py)
   fig10      cache-management ablation           (benchmarks/ablation.py)
   thm31      scheduler approximation bound       (benchmarks/scheduler_bound.py)
-  roofline   per-cell roofline terms from dryrun (benchmarks/roofline.py)
   splice     recovery→GEMM staging microbench    (benchmarks/splice.py)
   planner    §3.4 plan_pools online-speed bench  (benchmarks/planner_bench.py)
 """
@@ -24,12 +22,10 @@ from benchmarks.common import Rows
 MODULES = {
     "fig23": "benchmarks.compression",
     "fig4": "benchmarks.overlap",
-    "fig7": "benchmarks.serving_latency",
     "fig8": "benchmarks.throughput",
     "fig9": "benchmarks.e2e",
     "fig10": "benchmarks.ablation",
     "thm31": "benchmarks.scheduler_bound",
-    "roofline": "benchmarks.roofline",
     "splice": "benchmarks.splice",
     "planner": "benchmarks.planner_bench",
 }

@@ -85,6 +85,7 @@ from repro.core.faults import (FetchError, FetchTimeout, PeerLinkError,
 from repro.core.scheduler import build_blocks
 from repro.core.slab import (DevicePlanes, DeviceSlabCache, PeerRef,
                              PeerSlabMesh, SlotRef)
+from repro.core.spans import span
 from repro.core.states import CState, Task
 from repro.core.store import ExpertStore
 from repro.core.tiers import DEFAULT_STACK, PEER_STACK
@@ -258,9 +259,11 @@ class FetchHandle:
         instead of blocking forever on a dead pipeline."""
         eng = self._engine
         dl = eng.fetch_deadline_s if deadline_s is None else deadline_s
-        t0 = time.perf_counter()
-        ok = ev.wait(dl)
-        self.wait_s = time.perf_counter() - t0
+        with span("zipmoe.fetch.wait", layer=self._job.layer,
+                  job=self._job.seq):
+            t0 = time.perf_counter()
+            ok = ev.wait(dl)
+            self.wait_s = time.perf_counter() - t0
         if not ok:
             with eng._cv:
                 eng.deadline_hits += 1
@@ -279,7 +282,7 @@ class FetchHandle:
                 list(job.expert_keys)
             ev = job.demand_ev if job.demand_keys else job.done_ev
             self._wait(ev, deadline_s)
-            out, stats = self._engine._collect(job, subset)
+            out, stats = self._collect(subset)
             self._result = (self._flatten(out), stats)
         return self._result
 
@@ -299,23 +302,24 @@ class FetchHandle:
         assert want <= set(job.expert_keys), (want, job.expert_keys)
         eng = self._engine
         dl = eng.fetch_deadline_s if deadline_s is None else deadline_s
-        t0 = time.perf_counter()
-        with eng._cv:
-            def ready():
-                # failed uids never land: treat them as ready so the wait
-                # ends and _collect raises the structured error instead
-                return all(job.metas[t.uid] in job.done_tensors
-                           or t.uid in job.failed_uids
-                           for t in job.tasks if t.expert_key in want)
-            while not (job.done_ev.is_set() or ready()):
-                if dl is not None and time.perf_counter() - t0 > dl:
-                    eng.deadline_hits += 1
-                    raise FetchTimeout(
-                        f"fetch job {job.seq} subset {sorted(want)} "
-                        f"incomplete after {dl}s")
-                eng._cv.wait(0.1)
-        self.wait_s = time.perf_counter() - t0
-        out, stats = eng._collect(job, sorted(want))
+        with span("zipmoe.fetch.wait", layer=l, job=job.seq):
+            t0 = time.perf_counter()
+            with eng._cv:
+                def ready():
+                    # failed uids never land: treat them as ready so the
+                    # wait ends and _collect raises the structured error
+                    return all(job.metas[t.uid] in job.done_tensors
+                               or t.uid in job.failed_uids
+                               for t in job.tasks if t.expert_key in want)
+                while not (job.done_ev.is_set() or ready()):
+                    if dl is not None and time.perf_counter() - t0 > dl:
+                        eng.deadline_hits += 1
+                        raise FetchTimeout(
+                            f"fetch job {job.seq} subset {sorted(want)} "
+                            f"incomplete after {dl}s")
+                    eng._cv.wait(0.1)
+            self.wait_s = time.perf_counter() - t0
+        out, stats = self._collect(sorted(want))
         return self._flatten(out), stats
 
     def spec_result(self, deadline_s: Optional[float] = None
@@ -328,10 +332,15 @@ class FetchHandle:
         job = self._job
         if self._spec_result is None:
             self._wait(job.done_ev, deadline_s)
-            out, stats = self._engine._collect(job, list(job.expert_keys),
-                                               strict=False)
+            out, stats = self._collect(list(job.expert_keys), strict=False)
             self._spec_result = (self._flatten(out), stats)
         return self._spec_result
+
+    def _collect(self, subset, strict: bool = True):
+        """The engine's ``_collect`` of this job, in its host span."""
+        job = self._job
+        with span("zipmoe.fetch.collect", layer=job.layer, job=job.seq):
+            return self._engine._collect(job, subset, strict=strict)
 
 
 class ZipMoEEngine:
@@ -648,10 +657,11 @@ class ZipMoEEngine:
         sm_np = (np.frombuffer(sm, np.uint8)
                  if isinstance(sm, (bytes, bytearray))
                  else np.asarray(sm))   # host-sync-ok: plane bytes, pre-upload
-        t0 = time.perf_counter()
-        out = recover_bf16_device(exp_np, sm_np, shape)
-        out.block_until_ready()     # host-sync-ok: timed splice, off decode thread
-        dt = time.perf_counter() - t0
+        with span("zipmoe.dec.splice"):
+            t0 = time.perf_counter()
+            out = recover_bf16_device(exp_np, sm_np, shape)
+            out.block_until_ready()  # host-sync-ok: timed, off decode thread
+            dt = time.perf_counter() - t0
         with self._cv:
             self.h2d_bytes += exp_np.nbytes + sm_np.nbytes
             self.splice_s += dt
@@ -671,8 +681,9 @@ class ZipMoEEngine:
         sm_np = (np.frombuffer(sm, np.uint8)
                  if isinstance(sm, (bytes, bytearray))
                  else np.asarray(sm))   # host-sync-ok: plane bytes, pre-upload
-        exp_d = jnp.asarray(exp_np.reshape(-1))
-        sm_d = jnp.asarray(sm_np.reshape(-1))
+        with span("zipmoe.dec.upload"):
+            exp_d = jnp.asarray(exp_np.reshape(-1))
+            sm_d = jnp.asarray(sm_np.reshape(-1))
         with self._cv:
             self.h2d_bytes += exp_np.nbytes + sm_np.nbytes
         return DevicePlanes(exp=exp_d, sm=sm_d, shape=tuple(shape))
@@ -1778,7 +1789,8 @@ class ZipMoEEngine:
                 with self._cv:
                     if (t.uid, k) in job.e_data:   # watchdog-requeue dedup
                         continue
-                data = self.store.read_e((l, e), tidx, k)
+                with span("zipmoe.io.read", layer=l, job=job.seq):
+                    data = self.store.read_e((l, e), tidx, k)
                 with self._cv:
                     job.stats.io_bytes += len(data)
                     job.e_data[(t.uid, k)] = data
@@ -1801,7 +1813,8 @@ class ZipMoEEngine:
             if not have:
                 if self.faults is not None:
                     self.faults.worker("io")
-                data = self.store.read_sm((l, e), tidx)
+                with span("zipmoe.io.read", layer=l, job=job.seq):
+                    data = self.store.read_sm((l, e), tidx)
                 with self._cv:
                     job.stats.io_bytes += len(data)
                     job.sm_data[t.uid] = data
@@ -1868,7 +1881,9 @@ class ZipMoEEngine:
                 # preallocated plane — concurrent workers never overlap, and
                 # _finish_tensor consumes the plane without a concatenate
                 try:
-                    self.store.decompress_e_into((l, e), tidx, k, data, buf)
+                    with span("zipmoe.dec.decompress", layer=l, job=seq):
+                        self.store.decompress_e_into((l, e), tidx, k, data,
+                                                     buf)
                     ok = True
                 except Exception as dec_exc:
                     ok = self._dec_recover(job, t, k, buf, dec_exc)
@@ -2162,7 +2177,8 @@ class ZipMoEEngine:
                 self._reconcile_peer(l)
         if self.device_cache:
             for l in {l for l, _ in subset}:
-                self._reconcile_slab(l)
+                with span("zipmoe.fetch.reconcile", layer=l):
+                    self._reconcile_slab(l)
             # fused-miss fix-up: DevicePlanes handed out above resolve to
             # real tensors now that the reconcile ran — to the payload's
             # fresh SlotRef when the fused admit landed the planes in a

@@ -437,8 +437,7 @@ def main():
           f"slab_writes={ov['slab_writes']} "
           f"slab_resident={ov['slab_resident']}")
     print(f"gemm: pad_frac={ov['pad_frac']:.3f} "
-          f"(real={ov['tokens_real']} padded={ov['tokens_padded']} rows) "
-          f"compiles={ov['gemm_compiles']}")
+          f"(real={ov['tokens_real']} padded={ov['tokens_padded']} rows)")
     print_sched_telemetry(zs, args)
     zs.close()
 

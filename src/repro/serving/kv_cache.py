@@ -126,6 +126,8 @@ class KVPagePool:
         self._tables: Dict[int, List[int]] = {}    # rid -> page ids
         self._slots: Dict[int, int] = {}           # rid -> slot id
         self._cap: Dict[int, int] = {}             # rid -> token capacity
+        # bytes of the step views gather() has materialised, all steps
+        self.gather_bytes = 0
 
     # -- accounting ------------------------------------------------------
     @property
@@ -223,6 +225,9 @@ class KVPagePool:
             for key, sub in slot.items():
                 view[key] = jax.tree.map(lambda x: x[slots], sub)
             out.append(view)
+        # shapes only: no wait on the device
+        self.gather_bytes += sum(x.size * x.dtype.itemsize
+                                 for x in jax.tree.leaves(out))
         return out
 
     def commit(self, caches: Sequence[Dict], rids: Sequence[int], positions):

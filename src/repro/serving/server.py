@@ -57,6 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.faults import StepFault
+from repro.core.spans import span
 from repro.serving.generate import make_steps, sample_tokens
 from repro.serving.kv_cache import KVPagePool, grow_cache
 
@@ -199,11 +200,13 @@ class BatchServer:
         active: List[_Slot] = []
         t0 = time.perf_counter()
         while self.queue or active:
-            self._admit(active, pool, t0)
+            with span("zipmoe.batch.admit"):
+                self._admit(active, pool, t0)
             rids = [s.req.rid for s in active]
             tokens = jnp.asarray([[s.next_tok] for s in active], jnp.int32)
             positions = np.asarray([s.pos for s in active], np.int32)
-            views = pool.gather(rids)  # gen-checked: KV pages, not slab slots
+            with span("zipmoe.kv.gather"):
+                views = pool.gather(rids)  # gen-checked: KV pages, not slabs
             try:
                 lg, views = self.zip.decode_rows(tokens, views, positions,
                                                  owners=rids)
@@ -228,29 +231,32 @@ class BatchServer:
                     if self.on_retire is not None:
                         self.on_retire(r)
                 continue
-            pool.commit(views, rids, positions)
+            with span("zipmoe.kv.commit"):
+                pool.commit(views, rids, positions)
             retired: List[_Slot] = []
-            for b, s in enumerate(active):
-                r = s.req
-                s.pos += 1
-                if s.pos < len(r.prompt):          # prefill-as-decode
-                    s.next_tok = int(r.prompt[s.pos])
-                    continue
-                row = lg[b, -1]
-                step_key = jax.random.fold_in(s.key, len(r.output))
-                tok = int(sample_tokens(row[None], step_key,
-                                        self.temperature)[0])
-                now = time.perf_counter()
-                if r.ttft is None:
-                    r.ttft = now - r.submitted
-                r.output.append(tok)
-                if r.record_logits:
-                    r.logits.append(np.asarray(row, np.float32))
-                s.next_tok = tok
-                if (len(r.output) >= r.max_new_tokens
-                        or (r.eos_token is not None and tok == r.eos_token)):
-                    r.done = now
-                    retired.append(s)
+            with span("zipmoe.batch.sample"):
+                for b, s in enumerate(active):
+                    r = s.req
+                    s.pos += 1
+                    if s.pos < len(r.prompt):      # prefill-as-decode
+                        s.next_tok = int(r.prompt[s.pos])
+                        continue
+                    row = lg[b, -1]
+                    step_key = jax.random.fold_in(s.key, len(r.output))
+                    tok = int(sample_tokens(row[None], step_key,
+                                            self.temperature)[0])
+                    now = time.perf_counter()
+                    if r.ttft is None:
+                        r.ttft = now - r.submitted
+                    r.output.append(tok)
+                    if r.record_logits:
+                        r.logits.append(np.asarray(row, np.float32))
+                    s.next_tok = tok
+                    if (len(r.output) >= r.max_new_tokens
+                            or (r.eos_token is not None
+                                and tok == r.eos_token)):
+                        r.done = now
+                        retired.append(s)
             for s in retired:                      # free pages, backfill next
                 pool.free(s.req.rid)
                 active.remove(s)

@@ -92,6 +92,7 @@ from repro.core.engine import FetchHandle, ZipMoEEngine
 from repro.core.faults import FetchError, FetchTimeout, StepFault
 from repro.core.profiles import GemmProfiler
 from repro.core.slab import SlotRef
+from repro.core.spans import span
 from repro.core.store import ExpertStore
 from repro.kernels.moe_gemm import pick_block
 from repro.kernels.ops import (bucket_rows, fused_zip_gemm,
@@ -248,9 +249,7 @@ class ZipServer:
             "fault_refetches": 0,    # demand re-fetches of failed spec work
             "tokens_real": 0,        # routed (token, expert) pairs per GEMM
             "tokens_padded": 0,      # GEMM rows actually computed (w/ pads)
-            "gemm_compiles": 0,      # distinct expert-GEMM shape keys seen
         }
-        self._gemm_shapes: set = set()
 
     def close(self):
         self.engine.shutdown()
@@ -393,15 +392,16 @@ class ZipServer:
         double-counts wall time or bytes."""
         ov = self.overlap_stats
         live, io = [], 0
-        for h, s in self._pending.get(layer_idx, []):
-            if h.done():
-                _, st = h.spec_result()    # background work: fully hidden
-                if not getattr(h, "_drained_stats", False):
-                    h._drained_stats = True
-                    ov["fetch_wall_s"] += st.wall
-                    io += st.io_bytes
-            else:
-                live.append((h, s))
+        with span("zipmoe.fetch.drain", layer=layer_idx):
+            for h, s in self._pending.get(layer_idx, []):
+                if h.done():
+                    _, st = h.spec_result()  # background work: fully hidden
+                    if not getattr(h, "_drained_stats", False):
+                        h._drained_stats = True
+                        ov["fetch_wall_s"] += st.wall
+                        io += st.io_bytes
+                else:
+                    live.append((h, s))
         if layer_idx in self._pending:
             self._pending[layer_idx] = live
         return io
@@ -418,37 +418,39 @@ class ZipServer:
         In-flight experts are excluded from every layer's prediction (their
         job already reconstructs them — no duplicate work) but stay covered
         through their own pending entry."""
-        pred = (self._predict(layer_idx, batch,
-                              set(demand_ids) | self._in_flight(layer_idx))
-                if self.prefetch else [])
-        parts = []
-        if demand_ids or pred:
-            parts.append((layer_idx, demand_ids, pred,
-                          self._p_times_for(layer_idx,
-                                            list(demand_ids) + pred, batch)))
-        extra: List[Tuple[int, List[int]]] = []
-        if self.prefetch and self.cross_layer_depth:
-            for j in self._moe_layers_after(layer_idx,
-                                            self.cross_layer_depth):
-                pred_j = self._predict(j, batch, self._in_flight(j))
-                if pred_j:
-                    parts.append((j, [], pred_j,
-                                  self._p_times_for(j, pred_j, batch)))
-                    extra.append((j, pred_j))
-        if not parts:
-            return None
-        h = self.engine.submit_steps(parts)
-        if self.prefetch:
-            # the demand half counts as predicted for the NEXT step too: it
-            # is reconstructed by this very job, so a re-selected expert is
-            # a prediction hit, never a sticky demand refetch
+        with span("zipmoe.prefetch_submit", layer=layer_idx):
+            pred = (self._predict(layer_idx, batch, set(demand_ids)
+                                  | self._in_flight(layer_idx))
+                    if self.prefetch else [])
+            parts = []
             if demand_ids or pred:
-                self._pending.setdefault(layer_idx, []).append(
-                    (h, frozenset(pred) | set(demand_ids)))
-            for j, pred_j in extra:
-                self._pending.setdefault(j, []).append(
-                    (h, frozenset(pred_j)))
-        return h
+                parts.append((layer_idx, demand_ids, pred,
+                              self._p_times_for(layer_idx,
+                                                list(demand_ids) + pred,
+                                                batch)))
+            extra: List[Tuple[int, List[int]]] = []
+            if self.prefetch and self.cross_layer_depth:
+                for j in self._moe_layers_after(layer_idx,
+                                                self.cross_layer_depth):
+                    pred_j = self._predict(j, batch, self._in_flight(j))
+                    if pred_j:
+                        parts.append((j, [], pred_j,
+                                      self._p_times_for(j, pred_j, batch)))
+                        extra.append((j, pred_j))
+            if not parts:
+                return None
+            h = self.engine.submit_steps(parts)
+            if self.prefetch:
+                # the demand half counts as predicted for the NEXT step too:
+                # it is reconstructed by this very job, so a re-selected
+                # expert is a prediction hit, never a sticky demand refetch
+                if demand_ids or pred:
+                    self._pending.setdefault(layer_idx, []).append(
+                        (h, frozenset(pred) | set(demand_ids)))
+                for j, pred_j in extra:
+                    self._pending.setdefault(j, []).append(
+                        (h, frozenset(pred_j)))
+            return h
 
     def _issue_prefetch(self, layer_idx: Optional[int], batch: int):
         """Cold-start speculative submission (no demand half) for a layer
@@ -495,10 +497,12 @@ class ZipServer:
         # prediction jobs: `missing` is disjoint from every in-flight
         # prediction by construction (no duplicate work is possible), and
         # the urgent job jumps the I/O queue so it overlaps their tails
-        h_m = (self.engine.prefetch_experts(
-                   layer_idx, missing,
-                   self._p_times_for(layer_idx, missing, batch))
-               if missing else None)
+        h_m = None
+        if missing:
+            with span("zipmoe.prefetch_submit", layer=layer_idx):
+                h_m = self.engine.prefetch_experts(
+                    layer_idx, missing,
+                    self._p_times_for(layer_idx, missing, batch))
         if h_m is not None and self.prefetch:
             # the fallback job joins the pending list like any submission:
             # its experts are in flight, so the end-of-step prediction won't
@@ -810,13 +814,6 @@ class ZipServer:
         self.engine.count_w_copy(int(w.size) * w.dtype.itemsize)
         return w, np.arange(len(ids), dtype=np.int32)
 
-    def _note_gemm_shape(self, *key):
-        """Count DISTINCT expert-GEMM shape keys (jit-cache churn proxy —
-        every new key is one more compile; see ``bucket_rows``)."""
-        if key not in self._gemm_shapes:
-            self._gemm_shapes.add(key)
-            self.overlap_stats["gemm_compiles"] += 1
-
     def _ffn_grouped(self, x, top_p, top_i, weights, ids):  # hot-path
         """Gather-by-expert batched FFN on the grouped-GEMM kernel."""
         B, _, d = x.shape
@@ -829,7 +826,6 @@ class ZipServer:
             return self._stack_weights(name, weights, ids)
 
         C = xg.shape[1]
-        self._note_gemm_shape("grouped", len(ids), C)
         gg = lambda a, w: grouped_expert_gemm(
             a, w, block_c=pick_block(C, 128), block_d=pick_block(a.shape[-1], 512),
             block_f=pick_block(w.shape[-1], 128))
@@ -857,30 +853,32 @@ class ZipServer:
         in-group token order match; pad rows only ever touch token B)."""
         B, _, d = x.shape
         block_c = 8
-        gather, gates, tile_row = self._gather_by_expert_ragged(
-            top_p, top_i, ids, block_c)
-        xf = x.reshape(B, d)
-        xpad = jnp.concatenate([xf, jnp.zeros((1, d), xf.dtype)])
-        xg = xpad[jnp.asarray(gather)]                     # [T, d]
-        self._note_gemm_shape("ragged", gather.size)
+        names = [n for n in ("w_gate", "w_up", "w_down")
+                 if n in weights[ids[0]]]
+        with span("zipmoe.ffn.stage"):
+            gather, gates, tile_row = self._gather_by_expert_ragged(
+                top_p, top_i, ids, block_c)
+            xf = x.reshape(B, d)
+            xpad = jnp.concatenate([xf, jnp.zeros((1, d), xf.dtype)])
+            xg = xpad[jnp.asarray(gather)]                 # [T, d]
+            src = {n: self._slab_sources(n, weights, ids) for n in names}
 
-        def sg(a, src):                                    # one megakernel
-            buf, slots = src
+        def sg(a, name):                                   # one megakernel
+            buf, slots = src[name]
             return slab_gemm(a, buf, slots[tile_row], block_c=block_c,
                              block_d=pick_block(a.shape[-1], 512),
                              block_f=pick_block(buf.shape[-1], 128))
 
-        if "w_gate" in weights[ids[0]]:
-            h = jax.nn.silu(sg(xg, self._slab_sources("w_gate", weights,
-                                                      ids))) * \
-                sg(xg, self._slab_sources("w_up", weights, ids))
-        else:
-            h = jax.nn.gelu(sg(xg, self._slab_sources("w_up", weights, ids)))
-        eout = sg(h, self._slab_sources("w_down", weights, ids))   # [T, d]
-        comb = jnp.zeros((B + 1, d), jnp.float32).at[
-            jnp.asarray(gather)].add(
-            jnp.asarray(gates[:, None]) * eout.astype(jnp.float32))
-        return comb[:B].astype(x.dtype).reshape(B, 1, d)
+        with span("zipmoe.ffn.gemm"):
+            if "w_gate" in src:
+                h = jax.nn.silu(sg(xg, "w_gate")) * sg(xg, "w_up")
+            else:
+                h = jax.nn.gelu(sg(xg, "w_up"))
+            eout = sg(h, "w_down")                         # [T, d]
+            comb = jnp.zeros((B + 1, d), jnp.float32).at[
+                jnp.asarray(gather)].add(
+                jnp.asarray(gates[:, None]) * eout.astype(jnp.float32))
+            return comb[:B].astype(x.dtype).reshape(B, 1, d)
 
     def _ffn_zip_gemm(self, x, top_p, top_i, weights, ids):
         """Fused recovery+GEMM, ONE batched launch per projection: expert
@@ -895,7 +893,6 @@ class ZipServer:
         xpad = jnp.concatenate([xf, jnp.zeros((1, d), xf.dtype)])
         xg = xpad[jnp.asarray(gather)]                      # [Ea, C, d]
         C = xg.shape[1]
-        self._note_gemm_shape("zip", len(ids), C)
 
         def planes(name):
             ps: List[BitPlanes] = [weights[e][name] for e in ids]
@@ -976,12 +973,14 @@ class ZipServer:
         while per-request accounting runs on pure residency queries."""
         cfg = self.cfg
         ffn = lp["ffn"]
-        top_p, top_i, _ = route(ffn["router"], x, cfg)       # [B,1,k]
-        ids = sorted({int(e) for e in np.asarray(top_i).reshape(-1)})
+        with span("zipmoe.route", layer=layer_idx):
+            top_p, top_i, _ = route(ffn["router"], x, cfg)   # [B,1,k]
+            ids = sorted({int(e) for e in np.asarray(top_i).reshape(-1)})
         B = x.shape[0]
         self._last_ids[layer_idx] = ids
         if owners is not None:
-            self._note_request_access(layer_idx, top_i, owners)
+            with span("zipmoe.req_accounting", layer=layer_idx):
+                self._note_request_access(layer_idx, top_i, owners)
         # expert-weight transfer attributed to this layer-step (background
         # reconstruction charges the step it lands in — approximate but
         # exact in the two cases that matter: 0 on a full cache hit, and
@@ -1037,7 +1036,8 @@ class ZipServer:
                 self.profiler.record(layer_idx, len(ids), cols,
                                      time.perf_counter() - t_ffn)
         if "shared" in ffn:
-            y = y + apply_mlp(ffn["shared"], x, cfg)
+            with span("zipmoe.shared_ffn", layer=layer_idx):
+                y = y + apply_mlp(ffn["shared"], x, cfg)
         self.stats.append({"layer": layer_idx, "fetch_s": fetch_s,
                            "blocked_s": blocked_s, "io_bytes": io_bytes,
                            "n_experts": len(ids),
@@ -1049,41 +1049,8 @@ class ZipServer:
     def decode_step(self, tokens: jnp.ndarray, caches: list, pos: int
                     ) -> Tuple[jnp.ndarray, list]:  # hot-path
         """tokens: [B, 1] -> (logits [B,1,V], caches)."""
-        cfg = self.cfg
-        p = self.globals
-        x = p["embed"]["tok"][tokens]
-        if cfg.pos == "learned":
-            x = x + p["embed"]["pos"][pos][None, None]
-        new_caches = []
-        # loop-ok: per-LAYER structure (hot-path bans per-EXPERT loops;
-        # expert work inside goes through the grouped-GEMM path)
-        for idx, (lp, cache) in enumerate(zip(self.layers, caches)):
-            h = apply_norm(lp["norm1"], x, cfg)
-            if "attn" in lp:
-                if cfg.attn == "mla":
-                    y, kv = attn_lib.mla_decode(lp["attn"], h, cfg,
-                                                cache["kv"], jnp.int32(pos))
-                else:
-                    y, kv = attn_lib.gqa_decode(lp["attn"], h, cfg,
-                                                cache["kv"], jnp.int32(pos))
-                nc = {"kv": kv}
-            else:
-                y, sc = mamba_lib.mamba_decode(lp["mamba"], h, cfg, cache["ssm"])
-                nc = {"ssm": sc}
-            x = x + y
-            if "ffn" in lp:
-                h2 = apply_norm(lp["norm2"], x, cfg)
-                if "router" in lp["ffn"]:
-                    x = x + self._zip_moe_ffn(lp, h2, idx)
-                else:
-                    x = x + apply_mlp(lp["ffn"], h2, cfg)
-            new_caches.append(nc)
-        x = apply_norm(p["final_norm"], x, cfg)
-        w = p["embed"]["tok"].T if cfg.tie_embeddings else p["lm_head"]["w"]
-        if self._auto_depth:
-            self._tune_depth()
-        self.engine.note_step()       # windowed cache telemetry step clock
-        return x @ w, new_caches
+        with span("zipmoe.step"):
+            return self._forward(tokens, caches, jnp.int32(pos), None)
 
     def decode_rows(self, tokens: jnp.ndarray, caches: list, positions,
                     owners=None) -> Tuple[jnp.ndarray, list]:  # hot-path
@@ -1099,45 +1066,61 @@ class ZipServer:
         active set as shared multi-tenant resources.  Returns
         (logits [B, 1, V], updated caches).
         """
+        with span("zipmoe.step"):
+            lg, new_caches = self._forward(
+                tokens, caches, jnp.asarray(positions, jnp.int32), owners)
+            for rid in owners or ():
+                self.req_stats.setdefault(
+                    rid, {"accesses": 0, "hits": 0, "steps": 0})["steps"] += 1
+            return lg, new_caches
+
+    def _forward(self, tokens, caches: list, pos, owners
+                 ) -> Tuple[jnp.ndarray, list]:  # hot-path
+        """The layer loop of one decode step.  ``pos`` is a scalar position
+        (``decode_step``: every row at the same one) or int32 [B] (rows at
+        their own, ``decode_rows``); ``owners`` as in ``decode_rows``."""
         cfg = self.cfg
         p = self.globals
-        positions = jnp.asarray(positions, jnp.int32)
+        rows = pos.ndim == 1
         x = p["embed"]["tok"][tokens]
         if cfg.pos == "learned":
-            x = x + p["embed"]["pos"][positions][:, None]
+            x = x + (p["embed"]["pos"][pos][:, None] if rows
+                     else p["embed"]["pos"][pos][None, None])
+        if cfg.attn == "mla":
+            attend = attn_lib.mla_decode_rows if rows else attn_lib.mla_decode
+        else:
+            attend = attn_lib.gqa_decode_rows if rows else attn_lib.gqa_decode
         new_caches = []
         # loop-ok: per-LAYER structure (hot-path bans per-EXPERT loops;
         # expert work inside goes through the grouped-GEMM path)
         for idx, (lp, cache) in enumerate(zip(self.layers, caches)):
-            h = apply_norm(lp["norm1"], x, cfg)
-            if "attn" in lp:
-                if cfg.attn == "mla":
-                    y, kv = attn_lib.mla_decode_rows(lp["attn"], h, cfg,
-                                                     cache["kv"], positions)
+            with span("zipmoe.attn", layer=idx):
+                h = apply_norm(lp["norm1"], x, cfg)
+                if "attn" in lp:
+                    y, kv = attend(lp["attn"], h, cfg, cache["kv"], pos)
+                    nc = {"kv": kv}
                 else:
-                    y, kv = attn_lib.gqa_decode_rows(lp["attn"], h, cfg,
-                                                     cache["kv"], positions)
-                nc = {"kv": kv}
-            else:
-                y, sc = mamba_lib.mamba_decode(lp["mamba"], h, cfg, cache["ssm"])
-                nc = {"ssm": sc}
-            x = x + y
+                    y, sc = mamba_lib.mamba_decode(lp["mamba"], h, cfg,
+                                                   cache["ssm"])
+                    nc = {"ssm": sc}
+                x = x + y
             if "ffn" in lp:
                 h2 = apply_norm(lp["norm2"], x, cfg)
                 if "router" in lp["ffn"]:
                     x = x + self._zip_moe_ffn(lp, h2, idx, owners=owners)
                 else:
-                    x = x + apply_mlp(lp["ffn"], h2, cfg)
+                    with span("zipmoe.dense_ffn", layer=idx):
+                        x = x + apply_mlp(lp["ffn"], h2, cfg)
             new_caches.append(nc)
-        x = apply_norm(p["final_norm"], x, cfg)
-        w = p["embed"]["tok"].T if cfg.tie_embeddings else p["lm_head"]["w"]
-        for rid in owners or ():
-            self.req_stats.setdefault(
-                rid, {"accesses": 0, "hits": 0, "steps": 0})["steps"] += 1
+        with span("zipmoe.lm_head"):
+            x = apply_norm(p["final_norm"], x, cfg)
+            w = p["embed"]["tok"].T if cfg.tie_embeddings \
+                else p["lm_head"]["w"]
+            lg = x @ w
         if self._auto_depth:
             self._tune_depth()
         self.engine.note_step()       # windowed cache telemetry step clock
-        return x @ w, new_caches
+        return lg, new_caches
 
     def drain_pending(self) -> int:
         """Finish every in-flight prediction job and credit its stats —
@@ -1148,18 +1131,19 @@ class ZipServer:
         ov = self.overlap_stats
         io = 0
         for layer in list(self._pending):
-            for h, _ in self._pending[layer]:
-                try:
-                    _, st = h.spec_result()
-                except FetchTimeout:
-                    # a hung speculative job must not wedge shutdown: drop
-                    # the handle (the deadline hit is already counted by
-                    # the engine) and keep draining the rest
-                    continue
-                if not getattr(h, "_drained_stats", False):
-                    h._drained_stats = True
-                    ov["fetch_wall_s"] += st.wall
-                    io += st.io_bytes
+            with span("zipmoe.fetch.drain", layer=layer):
+                for h, _ in self._pending[layer]:
+                    try:
+                        _, st = h.spec_result()
+                    except FetchTimeout:
+                        # a hung speculative job must not wedge shutdown:
+                        # drop the handle (the deadline hit is already
+                        # counted by the engine) and keep draining the rest
+                        continue
+                    if not getattr(h, "_drained_stats", False):
+                        h._drained_stats = True
+                        ov["fetch_wall_s"] += st.wall
+                        io += st.io_bytes
             self._pending[layer] = []
         return io
 

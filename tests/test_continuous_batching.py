@@ -281,6 +281,25 @@ def test_page_pool_mixed_length_gather_and_overflow(cfg2):
     pool.commit(views, [1, 2], np.asarray([3, 10], np.int32))  # last valid
 
 
+def test_page_pool_counts_gathered_bytes(cfg2):
+    """``gather_bytes`` adds the bytes of every view ``gather`` builds:
+    ``T_pad`` (the longest allocation) times the per-token KV bytes of
+    every row, plus the seq-free leaves; it only rises."""
+    pool = KVPagePool(cfg2, page_size=4, n_pages=8, max_slots=3)
+    assert pool.gather_bytes == 0
+    pool.alloc(1, 4)                                   # 1 page
+    pool.alloc(2, 11)                                  # 3 pages
+    views = pool.gather([1, 2])
+    one = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(views))
+    per_token = pool.page_nbytes() // pool.page_size
+    assert one == 2 * 12 * per_token + 2 * pool.slot_nbytes()
+    assert pool.gather_bytes == one
+    pool.gather([1])                                   # T_pad 4: smaller
+    assert one < pool.gather_bytes < 2 * one
+    pool.gather([1, 2])
+    assert pool.gather_bytes == 2 * one + 4 * per_token + pool.slot_nbytes()
+
+
 # ---------------------------------------------------------------------------
 # BatchServer retirement edge cases
 # ---------------------------------------------------------------------------

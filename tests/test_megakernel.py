@@ -193,7 +193,6 @@ def test_ragged_vs_grouped_bitidentical(moe2_setup, mode_kw):
         ov = zs_r.overlap_summary()
         assert ov["tokens_real"] > 0
         assert 0.0 <= ov["pad_frac"] < 1.0
-        assert ov["gemm_compiles"] > 0
     finally:
         zs_g.close()
         zs_r.close()
