@@ -54,7 +54,11 @@ Two beyond-loop mechanisms turn the I/O-bound sync path compute-centric
   fully cache-hit decode step moves **zero** expert-weight bytes
   host→device and stages **zero** weight-copy bytes
   (``overlap_summary()['h2d_bytes']`` / ``['w_copy_bytes']``,
-  regression-tested).
+  regression-tested).  A *mixed* step (slab rows beside fresh
+  reconstructions no slot took) stages each layer's weight stacks in ONE
+  jitted call (``_stage_rows``: the fresh rows stacked, each slab row
+  copied in by slot, compiled once per count of selected experts)
+  instead of a slot read per expert and a stack per tensor.
 * **Byte-budgeted live pool planning** (``mem_budget=...``) — instead of
   fixed per-layer expert counts, one global byte budget is split across
   MoE layers by observed activity and each layer's F/C/S/E partition is
@@ -118,6 +122,45 @@ def _planes_recover(exp: np.ndarray, sm, shape) -> BitPlanes:
     sm_arr = (np.frombuffer(sm, np.uint8)
               if isinstance(sm, (bytes, bytearray)) else np.asarray(sm))
     return BitPlanes(np.asarray(exp), sm_arr, tuple(shape))
+
+
+@jax.jit
+def _stage_rows(bufs, rows, fresh):  # hot-path
+    """A mixed layer-step's stacked weight sources in ONE program.
+
+    ``bufs``: the layer slab's buffers, one per tensor name (read, never
+    donated); ``rows``: int32 ``[names, n]``, a row's slab slot or -1 where
+    the row is fresh; ``fresh``: per name, n ``[*shape]`` operands (the
+    fresh arrays, a shared placeholder on slab rows).  Returns per name the
+    ``[n, *shape]`` stack in row order: row i is ``bufs[name][rows[i]]`` or
+    ``fresh[name][i]``, copied bitwise.  The compiled shapes follow n and
+    the slab alone, not how many rows are fresh.
+
+    The operands are stacked once, then a loop overwrites each slab row in
+    place with one dynamic slice of its slot: no gather, whose TPU lowering
+    copies the whole slab, and no n-way select, which compiles slowly."""
+    def stage(buf, r, ops):
+        def put(i, st):
+            return jax.lax.cond(
+                r[i] >= 0,
+                lambda st: jax.lax.dynamic_update_index_in_dim(
+                    st, jax.lax.dynamic_index_in_dim(buf, r[i], 0, False),
+                    i, 0),
+                lambda st: st, st)
+        # host-sync-ok: traced stack of device operands, no upload
+        st = jnp.stack(ops)
+        return jax.lax.fori_loop(0, len(ops), put, st)
+    return tuple(stage(*a) for a in zip(bufs, rows, fresh))
+
+
+def _fresh_operands(vals, buf) -> tuple:
+    """One name's per-row ``_stage_rows`` operands: each fresh array as is,
+    each SlotRef row a shared placeholder of the tensor's shape (the name's
+    first fresh array, else the slab's slot 0), overwritten in the stack."""
+    ph = next((v for v in vals if not isinstance(v, SlotRef)), None)
+    if ph is None:
+        ph = buf[0]
+    return tuple(ph if isinstance(v, SlotRef) else v for v in vals)
 
 
 class ZipServer:
@@ -249,6 +292,9 @@ class ZipServer:
             "fault_refetches": 0,    # demand re-fetches of failed spec work
             "tokens_real": 0,        # routed (token, expert) pairs per GEMM
             "tokens_padded": 0,      # GEMM rows actually computed (w/ pads)
+            "stage_calls": 0,        # mixed layer-steps staged in one call
+            "stage_rows_slab": 0,    # of their rows, read from the slab
+            "stage_rows_fresh": 0,   # and taken from fresh device arrays
         }
 
     def close(self):
@@ -793,26 +839,55 @@ class ZipServer:
         self.engine.count_w_copy(int(w.size) * w.dtype.itemsize)
         return w
 
-    def _slab_sources(self, name: str, weights, ids):  # hot-path
-        """(buffer, slots) weight source for the slot-indexed ragged GEMM.
+    def _slab_sources(self, names, weights, ids):  # hot-path
+        """``{name: (buffer, slots)}`` weight sources of one layer-step for
+        the slot-indexed ragged GEMM (``names``: the tensor names, or one
+        name as a str).
 
-        Zero-copy fast path: every selected expert's tensor is a valid
-        SlotRef into the SAME layer slab — return the slab's buffer itself
-        (read in place by the megakernel) plus the per-expert slot vector;
-        no weight bytes move, nothing is charged.  Otherwise fall back to a
-        stacked [Ea, ...] batch (charged to ``w_copy_bytes``) indexed by
-        stack row."""
-        vals = [weights[e][name] for e in ids]
-        if vals and all(isinstance(v, SlotRef) for v in vals):
-            slab = vals[0].slab
-            if all(v.slab is slab and v.valid for v in vals):
-                return (slab.bufs[name],
-                        # host-sync-ok: host slot-index vector, no transfer
-                        np.asarray([v.slot for v in vals], np.int32))
-        # host-sync-ok: fallback — mixed/host steps stage a weight copy
-        w = jnp.stack([self._as_weight(v) for v in vals])
-        self.engine.count_w_copy(int(w.size) * w.dtype.itemsize)
-        return w, np.arange(len(ids), dtype=np.int32)
+        Zero-copy fast path: every selected expert's tensors are valid
+        SlotRefs into the SAME layer slab — return the slab's buffers
+        themselves (read in place by the megakernel) plus the per-expert
+        slot vectors; no weight bytes move, nothing is charged.
+
+        Mixed step (some experts are fresh device arrays no slot took):
+        ONE call of :func:`_stage_rows` builds every name's stacked
+        ``[Ea, ...]`` batch, slab rows read by slot inside the program,
+        indexed by stack row and charged to ``w_copy_bytes``.  Host ndarrays
+        (host mode) and anything else pay a per-name upload-and-stack; a
+        stale SlotRef reaches its ``read()`` there and trips."""
+        if isinstance(names, str):
+            names = (names,)
+        vals = {n: [weights[e][n] for e in ids] for n in names}
+        refs = [v for vs in vals.values() for v in vs if isinstance(v, SlotRef)]
+        slab = refs[0].slab if refs else None
+        in_slab = slab is not None and \
+            all(v.slab is slab and v.valid for v in refs)
+        if in_slab and len(refs) == len(names) * len(ids):
+            # host-sync-ok: host slot-index vectors, no transfer
+            return {n: (slab.bufs[n], np.asarray([v.slot for v in vals[n]],
+                                                 np.int32))
+                    for n in names}
+        order = np.arange(len(ids), dtype=np.int32)
+        if in_slab and all(isinstance(v, jax.Array) for vs in vals.values()
+                           for v in vs if not isinstance(v, SlotRef)):
+            # host-sync-ok: host row-source table (slot, or -1: fresh)
+            rows = np.asarray([[v.slot if isinstance(v, SlotRef) else -1
+                                for v in vals[n]] for n in names], np.int32)
+            stacks = _stage_rows(tuple(slab.bufs[n] for n in names), rows,
+                                 tuple(_fresh_operands(vals[n], slab.bufs[n])
+                                       for n in names))
+            from_slab = int((rows[0] >= 0).sum())
+            ov = self.overlap_stats
+            ov["stage_calls"] += 1
+            ov["stage_rows_slab"] += from_slab
+            ov["stage_rows_fresh"] += len(ids) - from_slab
+        else:
+            # host-sync-ok: host/unslabbed steps stage an uploaded copy
+            stacks = [jnp.stack([self._as_weight(v) for v in vals[n]])
+                      for n in names]
+        self.engine.count_w_copy(sum(int(w.size) * w.dtype.itemsize
+                                     for w in stacks))
+        return {n: (w, order) for n, w in zip(names, stacks)}
 
     def _ffn_grouped(self, x, top_p, top_i, weights, ids):  # hot-path
         """Gather-by-expert batched FFN on the grouped-GEMM kernel."""
@@ -861,7 +936,7 @@ class ZipServer:
             xf = x.reshape(B, d)
             xpad = jnp.concatenate([xf, jnp.zeros((1, d), xf.dtype)])
             xg = xpad[jnp.asarray(gather)]                 # [T, d]
-            src = {n: self._slab_sources(n, weights, ids) for n in names}
+            src = self._slab_sources(names, weights, ids)
 
         def sg(a, name):                                   # one megakernel
             buf, slots = src[name]

@@ -1,6 +1,7 @@
 """The served path's Pallas kernels, compiled by Mosaic for a described
-TPU v5e at Qwen1.5-MoE-A2.7B widths (d_model 2048, expert width 1408), plus
-the splice's bit-exactness over every bf16 pattern on the CPU.
+TPU v5e at Qwen1.5-MoE-A2.7B widths (d_model 2048, expert width 1408), and
+the mixed-step weight-staging program at those widths, plus the splice's
+bit-exactness over every bf16 pattern on the CPU.
 
 Nothing here runs on a chip: the TPU compiler compiles for a topology that
 is described, not attached, so a kernel the chip's compiler would refuse
@@ -109,3 +110,21 @@ def test_pick_block_divides_and_aligns():
     assert moe_gemm.pick_block(1408, 128) == 128
     assert moe_gemm.pick_block(64, 512) == 64        # below one tile: whole
     assert moe_gemm.pick_block(8, 128) == 8
+
+
+def test_stage_rows_compiles_for_v5e(one_chip):
+    """A mixed layer-step's staging program at Qwen's widest count (8 rows
+    × top-4 = 32 selected experts) compiles for the chip, and its working
+    memory beyond the stacks it returns stays below one expert's tensors:
+    nothing copies the slab or a second set of stacks."""
+    from repro.serving.zipserve import _stage_rows
+    n = 32
+    shapes = [SHAPES[0], SHAPES[0], SHAPES[1]]    # w_gate, w_up, w_down
+    bufs = tuple(_spec((CAP,) + s, jnp.bfloat16, one_chip) for s in shapes)
+    rows = _spec((len(shapes), n), jnp.int32, one_chip)
+    fresh = tuple(tuple(_spec(s, jnp.bfloat16, one_chip) for _ in range(n))
+                  for s in shapes)
+    mem = _stage_rows.lower(bufs, rows, fresh).compile().memory_analysis()
+    expert = len(shapes) * D_MODEL * D_EXPERT * 2
+    assert mem.output_size_in_bytes >= n * expert
+    assert mem.temp_size_in_bytes < expert

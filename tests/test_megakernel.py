@@ -17,6 +17,9 @@ serving integration):
   per-step gather copy,
 * the stale-SlotRef tripwire — a freed slot's ref crashes the slot-indexed
   weight-source resolution instead of being silently gathered,
+* mixed-step staging — a layer-step mixing slab rows and fresh arrays is
+  staged in ONE call, byte-identical to the per-expert stack it replaces,
+  with one compiled program per count of selected experts,
 * pad accounting — under skewed routing the ragged CSR tables compute
   strictly fewer GEMM rows than the pad-to-max-C tables (``pad_frac``).
 """
@@ -216,13 +219,31 @@ def test_zip_batched_vs_loop_bitidentical(moe2_setup):
         zs_b.close()
 
 
+def _one_fresh(zs):
+    """Make ``zs``'s fetches hand back each layer-step's first selected
+    expert as fresh device arrays (as an overflow miss no slot took): every
+    MoE layer-step of a fully slab-resident server turns mixed."""
+    acquire = zs._acquire_experts
+
+    def acquire_one_fresh(layer_idx, ids, batch):
+        weights, io_bytes, blocked = acquire(layer_idx, ids, batch)
+        weights = dict(weights)
+        weights[ids[0]] = {t: r.read() for t, r in weights[ids[0]].items()}
+        return weights, io_bytes, blocked
+
+    zs._acquire_experts = acquire_one_fresh
+
+
 def test_cache_hit_step_zero_w_copy_and_h2d(moe2_setup):
     """Acceptance regression: with every expert slab-resident, a ragged
-    decode step stages ZERO weight-copy bytes and ZERO h2d bytes; the
-    pre-megakernel grouped path keeps paying the per-step gather copy."""
+    decode step stages ZERO weight-copy bytes and ZERO h2d bytes and makes
+    no staging call; the pre-megakernel grouped path keeps paying the
+    per-step gather copy.  A mixed step then stages each MoE layer in ONE
+    call whose rows account for every selected expert, with logits
+    bit-identical to the grouped path's."""
     cfg, params, d = moe2_setup
     ample = {"F": cfg.n_experts, "C": 0, "S": 0, "E": 0}
-    deltas = {}
+    deltas, mixed_lg = {}, {}
     for impl in ("grouped", "ragged"):
         zs = ZipServer(params, cfg, d, L=3, pool_sizes=ample, prefetch=True,
                        device_cache=True, ffn_impl=impl)
@@ -234,19 +255,37 @@ def test_cache_hit_step_zero_w_copy_and_h2d(moe2_setup):
             lg, caches = zs.decode_step(tokens, caches, 11)  # jit warmup
             h2d0 = zs.engine.h2d_bytes
             w0 = zs.engine.w_copy_bytes
+            ov = zs.overlap_stats
+            stage0 = dict(ov)
             tok = jnp.argmax(lg[:, -1], -1).astype(jnp.int32)[:, None]
             for i in range(3):
                 lg, caches = zs.decode_step(tok, caches, 12 + i)
                 tok = jnp.argmax(lg[:, -1], -1).astype(jnp.int32)[:, None]
             deltas[impl] = (zs.engine.h2d_bytes - h2d0,
                             zs.engine.w_copy_bytes - w0)
+            n_moe = len(zs._moe_layers)
+            assert ov["stage_calls"] == stage0["stage_calls"] == 0
             if impl == "ragged":
                 assert all(s["w_copy_bytes"] == 0 for s in
-                           zs.stats[-3 * len(zs._moe_layers):])
+                           zs.stats[-3 * n_moe:])
+            _one_fresh(zs)
+            stage0 = dict(ov)
+            lg, caches = zs.decode_step(tok, caches, 15)
+            mixed_lg[impl] = np.asarray(lg, np.float32)
+            step = zs.stats[-n_moe:]
+            assert all(s["w_copy_bytes"] > 0 for s in step)
+            if impl == "ragged":
+                rows = {k: ov[k] - stage0[k] for k in
+                        ("stage_calls", "stage_rows_slab", "stage_rows_fresh")}
+                assert rows["stage_calls"] == n_moe, rows
+                assert rows["stage_rows_fresh"] == n_moe, rows
+                assert rows["stage_rows_slab"] + rows["stage_rows_fresh"] \
+                    == sum(s["n_experts"] for s in step), (rows, step)
         finally:
             zs.close()
     assert deltas["ragged"] == (0, 0), deltas
     assert deltas["grouped"][1] > 0, deltas   # the copy the megakernel kills
+    assert np.array_equal(mixed_lg["ragged"], mixed_lg["grouped"])
 
 
 def test_fused_splice_admit_taken_on_miss(moe2_setup):
@@ -279,6 +318,102 @@ def test_stale_slotref_trips_ragged_weight_source(moe2_setup):
         weights = {0: {"w_up": refs["w_up"]}}
         with pytest.raises(AssertionError):
             zs._slab_sources("w_up", weights, [0])
+    finally:
+        zs.close()
+
+
+def _mixed_step(zs, n, fresh, seed):
+    """A layer-step over experts 0..n-1 whose ``fresh`` rows (chosen at
+    random) are plain device arrays and whose other rows are SlotRefs into
+    a slab at shuffled slots; every expert is routed to by some row."""
+    rng = np.random.default_rng(seed)
+    shapes = zs.engine._slab(zs._moe_layers[0]).shapes
+    slab = DeviceSlabCache(99, shapes, capacity=8)
+    slots = rng.permutation(8)
+    fresh_rows = set(rng.choice(n, fresh, replace=False).tolist())
+    weights = {}
+    for e in range(n):
+        w = {t: jnp.asarray(rng.standard_normal(s) * 0.05, jnp.bfloat16)
+             for t, s in shapes.items()}
+        weights[e] = w if e in fresh_rows else slab.put(100 + int(slots[e]), w)
+    k = zs.cfg.top_k
+    B = -(-n // k) + 1
+    top_i = ((np.arange(B)[:, None] * k + np.arange(k)) % n)[:, None, :]
+    top_p = rng.uniform(0.1, 1.0, (B, 1, k)).astype(np.float32)
+    x = jnp.asarray(rng.standard_normal((B, 1, zs.cfg.d_model)), jnp.float32)
+    return x, top_p, top_i, weights, list(range(n)), sorted(shapes)
+
+
+@pytest.mark.parametrize("share", ["one", "half", "all_but_one"])
+@pytest.mark.parametrize("n", [3, 5, 8])
+def test_mixed_step_staged_in_one_call(moe2_setup, n, share):
+    """A mixed layer-step's weight sources come from ONE staging call whose
+    stacks are byte-identical to the per-expert stack they replace, charge
+    the same ``w_copy_bytes``, and feed a ragged FFN bit-identical to the
+    padded grouped path and, within f32 accumulation-order noise, to the
+    per-expert loop oracle."""
+    cfg, params, d = moe2_setup
+    fresh = {"one": 1, "half": n // 2, "all_but_one": n - 1}[share]
+    zs = ZipServer(params, cfg, d, L=2, pool_sizes=POOLS, prefetch=False,
+                   device_cache=True)
+    try:
+        x, top_p, top_i, weights, ids, names = _mixed_step(zs, n, fresh, n)
+        per_expert = {t: jnp.stack([zs._as_weight(weights[e][t]) for e in ids])
+                      for t in names}
+        ov = zs.overlap_stats
+        w0, ov0 = zs.engine.w_copy_bytes, dict(ov)
+        src = zs._slab_sources(names, weights, ids)
+        assert ov["stage_calls"] == ov0["stage_calls"] + 1
+        assert ov["stage_rows_fresh"] == ov0["stage_rows_fresh"] + fresh
+        assert ov["stage_rows_slab"] == ov0["stage_rows_slab"] + n - fresh
+        for t in names:
+            buf, slots = src[t]
+            assert np.array_equal(np.asarray(buf).view(np.uint16),
+                                  np.asarray(per_expert[t]).view(np.uint16))
+            assert np.array_equal(slots, np.arange(n))
+        assert zs.engine.w_copy_bytes - w0 == sum(
+            int(w.size) * w.dtype.itemsize for w in per_expert.values())
+        y = np.asarray(zs._ffn_ragged(x, top_p, top_i, weights, ids))
+        y_g = np.asarray(zs._ffn_grouped(x, top_p, top_i, weights, ids))
+        y_l = np.asarray(zs._ffn_loop(x, top_p, top_i, weights))
+        assert np.array_equal(y, y_g)
+        np.testing.assert_allclose(y, y_l, rtol=1e-5, atol=1e-6)
+    finally:
+        zs.close()
+
+
+def test_mixed_step_staging_compiles_once_per_count(moe2_setup):
+    """At a fixed count of selected experts, staging with 1, 2 and n-1
+    fresh experts (at any rows) runs ONE compiled program: the compiled
+    shapes follow the count alone, so a warm-up at one fresh expert covers
+    every mixed step of that count."""
+    from jax import monitoring
+    from repro.serving import zipserve
+    cfg, params, d = moe2_setup
+    n = 6
+    zs = ZipServer(params, cfg, d, L=2, pool_sizes=POOLS, prefetch=False,
+                   device_cache=True)
+    compiles = []
+
+    def on_duration(event, secs, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(secs)
+
+    try:
+        steps = [_mixed_step(zs, n, fresh, seed)
+                 for seed, fresh in enumerate((1, 2, n - 1, 1))]
+        _, _, _, weights, ids, names = steps[0]
+        jax.block_until_ready(zs._slab_sources(names, weights, ids))
+        entries = zipserve._stage_rows._cache_size()
+        monitoring.register_event_duration_secs_listener(on_duration)
+        try:
+            for _, _, _, weights, ids, names in steps[1:]:
+                jax.block_until_ready(zs._slab_sources(names, weights, ids))
+        finally:
+            monitoring.unregister_event_duration_listener(on_duration)
+        assert compiles == []
+        assert zipserve._stage_rows._cache_size() == entries
+        assert zs.overlap_stats["stage_calls"] == len(steps)
     finally:
         zs.close()
 
