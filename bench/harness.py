@@ -86,39 +86,52 @@ def device_info(chips: int, require_tpu: bool = True) -> dict:
             "peaks": peaks.get(d.device_kind)}
 
 
-def program_config(conf: dict):
-    """The program's config for ``conf``: the registered arch with the
-    configuration's depth and routing, every width checked against it."""
+# the program's options: fields of its config that a configuration sets
+# rather than checks
+OPTIONS = ("router_norm_topk",)
+
+
+def program_config(conf: dict, ref=None):
+    """The program's config for ``conf``: the registered arch at the
+    configuration's depth, with the options among the fields that the
+    configuration's reference module fixes (``program_fields``) set and
+    the other fields checked, and every layer's kind checked against the
+    reference's tensors for that layer."""
     from repro.launch import serve as serve_lib
+    ref = ref or spec.reference_module(conf)
+    want = dict(ref.program_fields(conf))
     cfg = serve_lib.model_config(conf["arch"], conf["num_hidden_layers"])
-    cfg = dataclasses.replace(cfg, router_norm_topk=bool(conf["norm_topk_prob"]))
-    E = conf.get("num_experts", conf.get("n_routed_experts"))
-    shared = conf.get("shared_expert_intermediate_size",
-                      conf.get("n_shared_experts", 0)
-                      * conf["moe_intermediate_size"])
-    want = {"d_model": conf["hidden_size"],
-            "n_heads": conf["num_attention_heads"],
-            "n_kv_heads": conf["num_key_value_heads"],
-            "vocab_size": conf["vocab_size"], "n_experts": E,
-            "top_k": conf["num_experts_per_tok"],
-            "d_expert": conf["moe_intermediate_size"],
-            "first_dense": conf.get("first_k_dense_replace", 0),
-            "rope_theta": float(conf["rope_theta"]),
-            "tie_embeddings": conf["tie_word_embeddings"]}
-    if "kv_lora_rank" in conf:
-        want.update(attn="mla", kv_lora_rank=conf["kv_lora_rank"],
-                    qk_nope_dim=conf["qk_nope_head_dim"],
-                    qk_rope_dim=conf["qk_rope_head_dim"],
-                    v_head_dim=conf["v_head_dim"], q_lora_rank=0,
-                    d_ff=conf["intermediate_size"])
-    else:
-        want.update(attn="gqa",
-                    head_dim=conf["hidden_size"] // conf["num_attention_heads"])
+    cfg = dataclasses.replace(cfg, **{k: want[k] for k in OPTIONS if k in want})
     got = {k: getattr(cfg, k) for k in want}
-    if got != want or cfg.n_shared_experts * cfg.d_expert != shared:
+    if got != want:
         raise ValueError(f"program config differs from {conf['arch']}: "
                          f"{got} != {want}")
+    _check_layers(cfg, ref.leaves(conf))
     return cfg
+
+
+def _check_layers(cfg, leaves) -> None:
+    """Each layer's mixer (``attn/`` or ``mamba/`` tensors), whether it
+    routes (``ffn/router``) and whether the head is tied (no
+    ``lm_head/w``), as the reference's tensors say, against the program."""
+    from repro.models import transformer
+    glob, layers = leaves
+    if len(layers) != cfg.n_layers:
+        raise ValueError(f"the reference has {len(layers)} layers, the "
+                         f"program {cfg.n_layers}")
+    tied = "lm_head/w" not in glob
+    if tied != cfg.tie_embeddings:
+        raise ValueError(f"the reference's head tied: {tied}; the "
+                         f"program's tie_embeddings: {cfg.tie_embeddings}")
+    for i, names in enumerate(layers):
+        mixer = ("attn" if any(n.startswith("attn/") for n in names) else
+                 "mamba" if any(n.startswith("mamba/") for n in names)
+                 else None)
+        theirs = (mixer, "ffn/router" in names)
+        mine = (transformer.mixer_kind(cfg, i), cfg.moe_layer(i))
+        if mine != theirs:
+            raise ValueError(f"layer {i}: the program has (mixer, routed) "
+                             f"{mine}, the reference {theirs}")
 
 
 def _counters(zs) -> dict:
@@ -179,8 +192,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
             compiles.append(time.perf_counter())
 
     monitoring.register_event_duration_secs_listener(on_duration)
-    pcfg = program_config(cell.config)
     ref = spec.reference_module(cell.config)
+    pcfg = program_config(cell.config, ref)
     tr = cell.traffic
     parts: Dict[str, float] = {}
     with _fresh_dir(os.path.join(cache, "store", cell.config_name)) as store:
@@ -273,12 +286,15 @@ def _build_server(cell, seed, pcfg, ref, store_dir, parts, log):
     from them, and ``BatchServer`` over ``ZipServer`` on it."""
     import jax
     from repro.core.store import build_store, drop_routed_experts
+    from repro.models.transformer import stack_layout
     from repro.serving.server import BatchServer
     from repro.serving.zipserve import ZipServer
 
     glob, layer_specs = ref.leaves(cell.config)
     t = time.perf_counter()
-    params = weights.program_params(seed, glob, layer_specs, pcfg.first_dense)
+    prefix, period, _ = stack_layout(pcfg)
+    params = weights.program_params(seed, glob, layer_specs, len(prefix),
+                                    period)
     jax.block_until_ready(params)
     parts["weights_s"] = time.perf_counter() - t
     t = time.perf_counter()

@@ -8,7 +8,7 @@ bfloat16 the configurations are served in.
 """
 from __future__ import annotations
 
-from typing import Callable, Dict
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
@@ -83,44 +83,78 @@ def moe(x, w: Dict, top_k: int, norm_topk: bool, scale: float, lowp):
     return y
 
 
-def final_hidden(conf: dict, seed: int, tokens: np.ndarray,
-                 leaves: Callable, layer_fn: Callable, lowp: bool):
-    """Embed ``tokens`` [N, T], run every layer with weights drawn one
-    layer at a time, and return the final-normed hidden state."""
-    glob, layers = leaves(conf)
+def moe_fields(conf: dict, n_experts: int, n_shared) -> dict:
+    """The program's config fields that a routed-expert decoder's
+    configuration fixes, whatever its attention (``program_fields``)."""
+    return {"d_model": conf["hidden_size"],
+            "n_heads": conf["num_attention_heads"],
+            "n_kv_heads": conf["num_key_value_heads"],
+            "vocab_size": conf["vocab_size"], "n_experts": n_experts,
+            "top_k": conf["num_experts_per_tok"],
+            "d_expert": conf["moe_intermediate_size"],
+            "n_shared_experts": n_shared,
+            "rope_theta": float(conf["rope_theta"]),
+            "tie_embeddings": conf["tie_word_embeddings"],
+            "router_norm_topk": bool(conf["norm_topk_prob"])}
+
+
+def head(g: Dict):
+    """The LM head [d, V]: ``lm_head/w``, or where the configuration ties
+    it (no such tensor) the embedding's transpose."""
+    return g["lm_head/w"] if "lm_head/w" in g else g["embed/tok"].T
+
+
+def final_hidden(conf: dict, seed: int, tokens: np.ndarray, mod,
+                 lowp: bool):
+    """Embed ``tokens`` [N, T] (scaled by the module's ``embed_scale``,
+    if it states one), run every layer of the reference module ``mod``
+    with weights drawn one layer at a time, and return the final-normed
+    hidden state."""
+    glob, layers = mod.leaves(conf)
     g = W.reference_globals(seed, glob)
     x = g["embed/tok"][jnp.asarray(tokens)]
+    scale = mod.embed_scale(conf) if hasattr(mod, "embed_scale") else 1
+    if scale != 1:
+        x = x * scale
     for l, spec in enumerate(layers):
         w = W.reference_layer(seed, l, spec)
-        x = jax.jit(layer_fn, static_argnums=(2, 3))(
+        x = jax.jit(mod.layer, static_argnums=(2, 3))(
             x, w, _freeze(conf), lowp)
         del w
     return rms_norm(x, g["final_norm/scale"], conf["rms_norm_eps"]), g
 
 
 def gaps(conf: dict, seed: int, tokens: np.ndarray, targets: np.ndarray,
-         leaves: Callable, layer_fn: Callable, control: bool = False):
+         mod, control: bool = False):
     """Per position of ``tokens`` [N, T]: how far the logit of
-    ``targets`` [N, T] (-1: not compared) lies below the reference's best.
+    ``targets`` [N, T] (-1: not compared) lies below the reference's best,
+    by the reference module ``mod``: its ``leaves`` and ``layer``, and,
+    where it states them, ``embed_scale(conf)`` and ``logits_divisor(conf)``
+    (default 1).  A configuration without ``lm_head/w`` ties the head
+    (``head``).
 
     With ``control``, also the same gap of the token the float8 control
     puts first.  Returns numpy arrays [N, T] (0 where not compared)."""
-    h, g = final_hidden(conf, seed, tokens, leaves, layer_fn, False)
-    head = g["lm_head/w"]
+    div = mod.logits_divisor(conf) if hasattr(mod, "logits_divisor") else 1
+    h, g = final_hidden(conf, seed, tokens, mod, False)
     tg = jnp.asarray(targets)
 
+    def logits(h, w, lowp):
+        lg = mm("ntd,dv->ntv", h, w, lowp)
+        return lg / div if div != 1 else lg
+
     @jax.jit
-    def gap_of(h, head, tg):
-        lg = jnp.einsum("ntd,dv->ntv", h, head, precision=HI)
+    def gap_of(h, w, tg):
+        lg = logits(h, w, False)
         best = jnp.max(lg, -1)
         got = jnp.take_along_axis(lg, jnp.maximum(tg, 0)[..., None], -1)[..., 0]
         return lg, jnp.where(tg >= 0, best - got, 0.0)
 
-    lg, gap = gap_of(h, head, tg)
+    lg, gap = gap_of(h, head(g), tg)
     out = {"gap": np.asarray(gap)}
     if control:
-        hl, gl = final_hidden(conf, seed, tokens, leaves, layer_fn, True)
-        top = jnp.argmax(mm("ntd,dv->ntv", hl, gl["lm_head/w"], True), -1)
+        hl, gl = final_hidden(conf, seed, tokens, mod, True)
+        top = jnp.argmax(logits(hl, head(gl), True), -1)
         best = jnp.max(lg, -1)
         got = jnp.take_along_axis(lg, top[..., None], -1)[..., 0]
         out["control_gap"] = np.asarray(jnp.where(tg >= 0, best - got, 0.0))

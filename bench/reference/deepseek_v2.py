@@ -14,9 +14,24 @@ type is ``default``) in the half-split layout.
 """
 from __future__ import annotations
 
+import sys
+
 from bench.reference import common as C
 
 BF16, F32 = "bfloat16", "float32"
+
+
+def program_fields(conf: dict) -> dict:
+    """The served program's config fields this configuration fixes: MLA
+    with no query low-rank path."""
+    return {**C.moe_fields(conf, conf["n_routed_experts"],
+                           conf["n_shared_experts"]),
+            "first_dense": conf["first_k_dense_replace"], "attn": "mla",
+            "kv_lora_rank": conf["kv_lora_rank"],
+            "qk_nope_dim": conf["qk_nope_head_dim"],
+            "qk_rope_dim": conf["qk_rope_head_dim"],
+            "v_head_dim": conf["v_head_dim"], "q_lora_rank": 0,
+            "d_ff": conf["intermediate_size"]}
 
 
 def leaves(conf: dict):
@@ -89,4 +104,5 @@ def layer(x, w, conf, lowp):
 
 
 def gaps(conf, seed, tokens, targets, control=False):
-    return C.gaps(conf, seed, tokens, targets, leaves, layer, control)
+    return C.gaps(conf, seed, tokens, targets, sys.modules[__name__],
+                  control)

@@ -9,11 +9,22 @@ no ``shared_expert_gate``; the configuration's ``assumed`` says so).
 """
 from __future__ import annotations
 
+import sys
+
 import jax.numpy as jnp
 
 from bench.reference import common as C
 
 BF16, F32 = "bfloat16", "float32"
+
+
+def program_fields(conf: dict) -> dict:
+    """The served program's config fields this configuration fixes."""
+    return {**C.moe_fields(conf, conf["num_experts"],
+                           conf["shared_expert_intermediate_size"]
+                           / conf["moe_intermediate_size"]),
+            "first_dense": 0, "attn": "gqa",
+            "head_dim": conf["hidden_size"] // conf["num_attention_heads"]}
 
 
 def leaves(conf: dict):
@@ -67,4 +78,5 @@ def layer(x, w, conf, lowp):
 
 
 def gaps(conf, seed, tokens, targets, control=False):
-    return C.gaps(conf, seed, tokens, targets, leaves, layer, control)
+    return C.gaps(conf, seed, tokens, targets, sys.modules[__name__],
+                  control)

@@ -68,7 +68,13 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
 
 
 def reference_module(config: Dict):
-    """The plain reference the configuration names."""
+    """The plain reference the configuration names: ``leaves(conf)`` (its
+    tensors in the served layout, per layer), ``layer`` (one layer's
+    forward pass), ``gaps`` (``common.gaps`` over itself) and
+    ``program_fields(conf)`` (the program's config fields it fixes); where
+    the model has them, ``state_flops(conf)`` (a state-space layer's
+    FLOPs a token beyond its weights), ``embed_scale(conf)`` and
+    ``logits_divisor(conf)``."""
     return importlib.import_module("bench.reference." + config["reference"])
 
 

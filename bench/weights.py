@@ -31,9 +31,12 @@ def seed_key(seed: int) -> jax.Array:
 
 
 def leaf(key, layer: int, name: str, shape, dtype, init) -> jax.Array:
-    """One tensor: ``"ones"``, or normal with standard deviation ``init``."""
+    """One tensor: ``"ones"``, ``"zeros"``, or normal with standard
+    deviation ``init``."""
     if init == "ones":
         return jnp.ones(shape, dtype)
+    if init == "zeros":
+        return jnp.zeros(shape, dtype)
     k = jax.random.fold_in(jax.random.fold_in(key, layer + 1),
                            zlib.crc32(name.encode()))
     return (jax.random.normal(k, shape, jnp.float32) * init).astype(dtype)
@@ -54,22 +57,50 @@ def _nest(flat: Dict[str, jax.Array]) -> dict:
     return out
 
 
+def _stack_groups(layers: List[Spec], first_dense: int,
+                 period: int) -> List[List[int]]:
+    """The layer indices of each ``sub_j`` of the program's stack: layer
+    ``first_dense + b*period + j`` is block ``b`` of ``sub_j``.  Layers of
+    one ``sub_j`` must have the same names, shapes and types."""
+    rest = len(layers) - first_dense
+    if rest < 0 or rest % period:
+        raise ValueError(f"{len(layers)} layers do not fill {first_dense} "
+                         f"prefix layers and blocks of {period}")
+    groups = [list(range(first_dense + j, len(layers), period))
+              for j in range(period)] if rest else []
+    kinds = [{n: (tuple(s), d) for n, (s, d, _) in spec.items()}
+             for spec in layers]
+    for l in range(first_dense, len(layers)):
+        j = (l - first_dense) % period
+        first = groups[j][0]
+        a, b = kinds[first], kinds[l]
+        if a != b:
+            diff = sorted(n for n in a.keys() | b.keys()
+                          if a.get(n) != b.get(n))
+            raise ValueError(f"layer {l} differs from layer {first}, which "
+                             f"shares sub_{j} with it, in {diff}")
+    return groups
+
+
 def program_params(seed: int, glob: Spec, layers: List[Spec],
-                   first_dense: int):
+                   first_dense: int, period: int = 1):
     """The whole model in the program's pytree, in one jitted call:
     ``{"embed", "final_norm", "lm_head", "decoder": {"prefix": [dense
-    layers], "stack": {"sub_0": layers stacked on a leading axis}}}``."""
+    layers], "stack": {"sub_j": layers first_dense + b*period + j stacked
+    over b on a leading axis}}}``, as ``transformer.stack_layout`` lays
+    out a period of ``period`` layer kinds."""
+    groups = _stack_groups(layers, first_dense, period)
 
     def make(key):
         p = _nest({n: leaf(key, -1, n, s, d, i)
                    for n, (s, d, i) in glob.items()})
         per = [layer_tensors(key, l, spec) for l, spec in enumerate(layers)]
-        stacked = per[first_dense:]
         p["decoder"] = {
             "prefix": [_nest(t) for t in per[:first_dense]],
-            "stack": ({"sub_0": _nest({n: jnp.stack([t[n] for t in stacked])
-                                       for n in stacked[0]})}
-                      if stacked else None)}
+            "stack": ({f"sub_{j}": _nest({n: jnp.stack([per[l][n] for l in ls])
+                                          for n in per[ls[0]]})
+                       for j, ls in enumerate(groups)}
+                      if groups else None)}
         return p
 
     return jax.jit(make)(seed_key(seed))

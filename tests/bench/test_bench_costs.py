@@ -67,3 +67,41 @@ def test_model_flops_per_token_deepseek():
     head = 2048 * 102400
     assert model.flops_per_token(conf, 0) == \
         2 * (5 * attn + dense + 4 * moe + head)
+
+
+@pytest.mark.parametrize("name", ["tiny-qwen2-moe.json", "tiny-deepseek-v2.json",
+                                  "qwen1.5-moe-a2.7b.l4", "deepseek-v2-lite.l5"])
+def test_flops_per_token_equals_the_parents(name):
+    """The same float at ``ctx`` 64 as before attention was charged by
+    layer (``parent-pins.json``), so ``step_mfu`` does not move."""
+    from conftest import _load
+    if name.endswith(".json"):
+        conf = _load(name)
+    else:
+        with open(os.path.join(ROOT, "bench", "configs", name + ".json")) as f:
+            conf = json.load(f)
+    assert model.flops_per_token(conf, 64) == \
+        _load("parent-pins.json")["flops_per_token_ctx64"][name]
+
+
+def test_hybrid_flops_charge_context_to_attention_layers_only():
+    """The tiny hybrid (16 layers: 2 attention, 14 Mamba; experts top-2
+    of 4 on the 8 odd layers, an MLP on the rest; a tied head): context
+    costs in the 2 attention layers alone, the tied head counts once as
+    the head, and each Mamba layer adds its module's state FLOPs."""
+    import tiny_hybrid
+    from conftest import _load
+    conf = _load("tiny-jamba.json")
+    attn = 64 * 64 + 2 * 64 * 32 + 64 * 64
+    mamba = 2 * 64 * 128 + 3 * 64 * 8 + 4 * 144 + 128 * 64
+    mlp = 3 * 64 * 96
+    moe = 64 * 4 + 2 * 3 * 64 * 96
+    head = 64 * 256
+    state = 2 * 3 * 128 * 8
+    assert tiny_hybrid.state_flops(conf) == state
+    base = model.flops_per_token(conf, 0, tiny_hybrid)
+    assert base == 2 * (2 * attn + 14 * mamba + 8 * mlp + 8 * moe + head) \
+        + 14 * state
+    # 2 layers attend over 64 positions: 4 heads of 16, scores and values
+    assert model.flops_per_token(conf, 64, tiny_hybrid) - base == \
+        2 * 2 * 4 * 64 * (16 + 16)

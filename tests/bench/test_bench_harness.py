@@ -1,5 +1,6 @@
 """The benchmark end to end on a tiny configuration, on the CPU, through
 its internals; the entry point's refusals."""
+import contextlib
 import json
 import os
 import shutil
@@ -111,10 +112,53 @@ def test_every_cell_finds_its_files():
         assert {m["name"] for m in cell.metrics(False)} >= {"setup_s"}
 
 
-@pytest.mark.parametrize("name", ["qwen1.5-moe-a2.7b.l4", "deepseek-v2-lite.l5"])
+@pytest.mark.parametrize("name", ["qwen1.5-moe-a2.7b.l4", "deepseek-v2-lite.l5",
+                                  "qwen1.5-moe-a2.7b.l4.resident"])
 def test_configuration_matches_the_program_at_published_widths(name):
     from bench import harness
     with open(os.path.join(ROOT, "bench", "configs", name + ".json")) as f:
         conf = json.load(f)
     cfg = harness.program_config(conf)
     assert cfg.n_layers == conf["num_hidden_layers"]
+
+
+def _conf(name):
+    from conftest import _load
+    if name.endswith(".json"):
+        return _load(name)
+    with open(os.path.join(ROOT, "bench", "configs", name + ".json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("name", ["tiny-qwen2-moe.json", "tiny-deepseek-v2.json",
+                                  "qwen1.5-moe-a2.7b.l4", "deepseek-v2-lite.l5"])
+def test_program_config_equals_the_parents(name):
+    """The fields each reference module fixes give the program config that
+    the harness's own checks gave before they moved (``parent-pins.json``)."""
+    import dataclasses
+    from bench import harness
+    from conftest import _load, tiny_widths
+    conf = _conf(name)
+    with tiny_widths(conf) if "test_program_widths" in conf else \
+            contextlib.nullcontext():
+        cfg = harness.program_config(conf)
+    assert dataclasses.asdict(cfg) == \
+        _load("parent-pins.json")["program_config"][name]
+
+
+@pytest.mark.parametrize("change,layer", [({"attn_layer_offset": 4}, 3),
+                                          ({"expert_layer_offset": 0}, 0)],
+                         ids=["attention-at-4", "experts-on-even-layers"])
+def test_program_config_refuses_layer_kinds_that_disagree(change, layer):
+    """The tiny hybrid with attention where the published Jamba has it
+    (offset 4; the program's is 3) or with experts on the even layers,
+    against the program's layout: refused at set-up, naming the first
+    layer whose kind differs."""
+    import tiny_hybrid
+    from bench import harness
+    from conftest import tiny_widths
+    conf = _conf("tiny-jamba.json")
+    with tiny_widths(conf):
+        harness.program_config(conf, tiny_hybrid)
+        with pytest.raises(ValueError, match=f"layer {layer}: the program"):
+            harness.program_config(dict(conf, **change), tiny_hybrid)
